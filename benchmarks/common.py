@@ -175,24 +175,15 @@ def elems_per_sec(n_elems: int, seconds: float) -> float:
 #
 # Reduction and scan are bandwidth-bound (the paper's premise): the useful
 # work per element is O(1), so the honest cross-machine metric is achieved
-# memory bandwidth against the host's peak, not raw wall clock. The peaks
-# below are deliberately round defaults per backend class; a real
-# measurement host overrides with REPRO_PEAK_GBPS (note: NOT one of the
-# policy env vars — those are parsed only by repro.core.policy).
-
-DEFAULT_PEAK_GBPS = {"cpu": 50.0, "gpu": 900.0, "tpu": 1200.0}
-ENV_PEAK_GBPS = "REPRO_PEAK_GBPS"
+# memory bandwidth against the device's published peak
+# (``repro.launch.peaks``, keyed by device kind; an unknown kind raises).
 
 
 def peak_gbps() -> float:
-    """This host's assumed peak memory bandwidth in GB/s:
-    ``$REPRO_PEAK_GBPS`` if set, else a per-backend-class default."""
-    env = os.environ.get(ENV_PEAK_GBPS, "").strip()
-    if env:
-        return float(env)
-    b = jax.default_backend()
-    b = "gpu" if b in ("cuda", "rocm") else b
-    return DEFAULT_PEAK_GBPS.get(b, DEFAULT_PEAK_GBPS["cpu"])
+    """This device's published peak memory bandwidth in GB/s."""
+    from repro.launch.peaks import peaks_for
+
+    return peaks_for().hbm_bytes_s / 1e9
 
 
 def bandwidth_model(bytes_moved: int, seconds: float) -> dict:

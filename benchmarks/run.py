@@ -65,6 +65,9 @@ def main(argv: list[str] | None = None) -> None:
 
     obs_cli.add_obs_args(ap)
     args = ap.parse_args(argv)
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     common.set_bench_backend(args.backend)
     common.set_bench_json_dir(args.json_dir)
 

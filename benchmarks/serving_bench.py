@@ -224,6 +224,9 @@ def main(argv=None) -> None:
 
     obs_cli.add_obs_args(ap)
     args = ap.parse_args(argv if argv is not None else [])
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
 
     mesh_ctx = None
     if args.mesh:

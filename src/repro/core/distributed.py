@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.scan import tcu_scan, tcu_weighted_scan
+from repro.kernels.layout import MXU_PRECISION
 
 
 def dist_reduce(x_local: jax.Array, axis_name: str) -> jax.Array:
@@ -45,7 +46,8 @@ def dist_exclusive_carry(local_total: jax.Array, axis_name: str) -> jax.Array:
     cols = jax.lax.broadcasted_iota(jnp.int32, (ndev, ndev), 1)
     l_mask = (rows > cols).astype(gathered.dtype)
     flat = gathered.reshape(ndev, -1)
-    carries = l_mask @ flat                                        # (ndev, -1)
+    carries = jnp.matmul(l_mask, flat,
+                         precision=MXU_PRECISION)                 # (ndev, -1)
     return carries[idx].reshape(gathered.shape[1:])
 
 
@@ -90,7 +92,7 @@ def weighted_exclusive_carry(
     # flatten the extra state dims so the combine is one batched matmul
     t = jnp.moveaxis(gathered_t.reshape((ndev,) + log_decay.shape + (-1,)),
                      0, -2)                             # (*D, ndev, extra)
-    s = m @ t                                           # inclusive H_i
+    s = jnp.matmul(m, t, precision=MXU_PRECISION)       # inclusive H_i
     excl = jnp.concatenate(
         [jnp.zeros_like(s[..., :1, :]), s[..., :-1, :]], axis=-2)
     return jnp.take(excl, idx, axis=-2).reshape(total.shape)

@@ -1,13 +1,13 @@
 """Pallas TPU kernels for the paper's compute hot-spots.
 
 Each kernel module pairs with a pure-jnp oracle in ``ref.py``; the public
-entry points live in ``ops.py`` and route through the version-shimmed
+entry points live in ``ops.py`` and route through the
 dispatch layer in ``backend.py`` (fused XLA vs Pallas tile vs interpret
 mode, selected by the active ``repro.core.policy.KernelPolicy`` — per
 call via ``policy=``/``path=``, or process-wide; the stable façade is
 ``repro.ops``):
 
-  backend.py          version shim + capability probes + pallas_op dispatch
+  backend.py          compiler params + capability probes + pallas_op dispatch
   tcu_reduce.py       matmul-form segmented reduction   (paper §4)
   tcu_scan.py         matmul-form segmented scan        (paper §5)
   matmul_scan.py      log-depth MatMulScan: carry-free local kernels +

@@ -1,15 +1,10 @@
-"""Version shim + multi-backend dispatch layer for every Pallas kernel in
-the repo.
+"""Multi-backend dispatch layer for every Pallas kernel in the repo.
 
-Why this exists: the Pallas private surfaces rename things across JAX
-releases (``pltpu.TPUCompilerParams`` on 0.4.x became ``pltpu.CompilerParams``
-on 0.5+, same drift on the Triton side, field sets move too). Hard-coding
-one spelling in each kernel broke all of them at once; this module is the
-single place that knows which JAX is installed and which accelerator is
-active. Kernels call :func:`compiler_params` instead of touching
-``pltpu``/``plgpu`` classes, and the public wrappers register with
-:func:`register_op` so every call site picks its execution path through one
-switch:
+This module is the single place that knows which accelerator is active
+and how Pallas compiler params are spelled. Kernels call
+:func:`compiler_params` instead of touching ``pltpu``/``plgpu`` classes,
+and the public wrappers register with :func:`register_op` so every call
+site picks its execution path through one switch:
 
   ``fused``      the XLA reference path (``repro.kernels.ref`` /
                  ``repro.core``) — default off-accelerator
@@ -30,7 +25,7 @@ switch:
 Which path runs is decided by the active :class:`repro.core.policy.
 KernelPolicy` — the single resolution algorithm for the whole repo. This
 module keeps the registry, the capability probes, and the compiler-params
-shim; selection state (path, per-op overrides, backend preference,
+builder; selection state (path, per-op overrides, backend preference,
 autotune mode, env-var parsing) lives entirely in ``repro.core.policy``.
 Precedence: per-call ``path=`` kwarg > per-call legacy ``use_pallas=``
 bool > per-call / active ``policy`` (whose process default is built from
@@ -53,7 +48,6 @@ is data, not constants.
 from __future__ import annotations
 
 import dataclasses
-import inspect
 import warnings
 from typing import Any, Callable
 
@@ -109,40 +103,16 @@ def native_tile_backend() -> str | None:
 
 
 # ---------------------------------------------------------------------------
-# compiler-params shim
-
-
-def compiler_params_cls() -> type:
-    """The Pallas-TPU compiler-params class under whichever name this JAX
-    uses (``CompilerParams`` on 0.5+, ``TPUCompilerParams`` on 0.4.x)."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    for name in ("CompilerParams", "TPUCompilerParams"):
-        cls = getattr(pltpu, name, None)
-        if cls is not None:
-            return cls
-    raise RuntimeError(
-        f"jax {jax.__version__}: no Pallas-TPU compiler-params class found; "
-        "the version shim in repro.kernels.backend needs a new spelling"
-    )
-
-
-def _accepted_fields(cls: type) -> set[str]:
-    if dataclasses.is_dataclass(cls):
-        return {f.name for f in dataclasses.fields(cls)}
-    return set(inspect.signature(cls).parameters)
+# compiler params
 
 
 def compiler_params(backend: str = "tpu", **kwargs: Any):
-    """Construct compiler params portably for either Pallas backend.
+    """Pallas compiler params for either backend.
 
-    ``backend="tpu"`` (default) builds the Pallas-TPU params;
-    ``backend="gpu"`` defers to the Triton shim in
+    ``backend="tpu"`` (default) builds ``pltpu.CompilerParams``;
+    ``backend="gpu"`` defers to the Triton module
     ``repro.kernels.triton.compat`` (the only module allowed to import
-    ``jax.experimental.pallas.triton``). Fields unknown to the installed
-    JAX (the field sets drift between releases) are dropped rather than
-    raising, so kernels can request newer knobs without pinning a JAX
-    version.
+    ``jax.experimental.pallas.triton``). An unknown field raises.
     """
     if backend in ("gpu", "triton"):
         from repro.kernels.triton import compat
@@ -152,11 +122,11 @@ def compiler_params(backend: str = "tpu", **kwargs: Any):
         raise ValueError(
             f"unknown compiler-params backend {backend!r}; "
             "expected 'tpu' or 'gpu'")
-    cls = compiler_params_cls()
-    fields = _accepted_fields(cls)
-    if "dimension_semantics" in kwargs and kwargs["dimension_semantics"]:
+    from jax.experimental.pallas import tpu as pltpu
+
+    if kwargs.get("dimension_semantics"):
         kwargs["dimension_semantics"] = tuple(kwargs["dimension_semantics"])
-    return cls(**{k: v for k, v in kwargs.items() if k in fields})
+    return pltpu.CompilerParams(**kwargs)
 
 
 # ---------------------------------------------------------------------------
@@ -206,8 +176,11 @@ class PallasOp:
     log-depth MatMulScan contenders per backend (scan family only; None
     elsewhere) — each must accept ``interpret=`` like the linear entries,
     which is how the label survives off-accelerator with interpreted
-    local kernels. ``knobs`` declares the family's tuning-knob schema
-    (from ``repro.core.policy.KNOB_SCHEMA``, keyed by the canonical op
+    local kernels. ``fits`` is the shape precondition of the ``tile``
+    entry (None: every shape fits) — called with the entry's arguments
+    and ``tuning=``; a call that does not fit runs the ``fused`` oracle
+    (see :func:`pallas_op`). ``knobs`` declares the family's tuning-knob
+    schema (from ``repro.core.policy.KNOB_SCHEMA``, keyed by the canonical op
     name); the default and sweep-candidate knob *values* live in
     ``repro.kernels.layout`` and are exposed here per backend so autotune
     and callers interrogate the registry, not the kernel files.
@@ -219,6 +192,7 @@ class PallasOp:
     tile_gpu: Callable[..., Any] | None = None
     tile_logdepth: Callable[..., Any] | None = None
     tile_logdepth_gpu: Callable[..., Any] | None = None
+    fits: Callable[..., bool] | None = None
     knobs: tuple = ()
 
     def _canonical(self) -> str:
@@ -246,14 +220,15 @@ def register_op(name: str, *, tile: Callable[..., Any],
                 fused: Callable[..., Any],
                 tile_gpu: Callable[..., Any] | None = None,
                 tile_logdepth: Callable[..., Any] | None = None,
-                tile_logdepth_gpu: Callable[..., Any] | None = None
+                tile_logdepth_gpu: Callable[..., Any] | None = None,
+                fits: Callable[..., bool] | None = None
                 ) -> PallasOp:
     from repro.core import policy as kpolicy  # deferred: avoids a cycle
 
     canon = kpolicy.OP_ALIASES.get(name, name)
     op = PallasOp(name=name, tile=tile, fused=fused, tile_gpu=tile_gpu,
                   tile_logdepth=tile_logdepth,
-                  tile_logdepth_gpu=tile_logdepth_gpu,
+                  tile_logdepth_gpu=tile_logdepth_gpu, fits=fits,
                   knobs=tuple(kpolicy.KNOB_SCHEMA.get(canon, ())))
     _REGISTRY[name] = op
     return op
@@ -295,9 +270,10 @@ def _call_shape(name: str, args: tuple) -> tuple:
     return None, None
 
 
-def _emit_invoke(name: str, n, dt, p) -> None:
+def _emit_invoke(name: str, n, dt, p, path: str) -> None:
     """One ``kernel_invoke`` event + counter per registry execution (only
-    called when an obs session is active)."""
+    called when an obs session is active). ``path`` is what ran: the
+    resolved label, or ``"oracle"`` for a shape the kernel does not fit."""
     sess = _obs.ACTIVE
     if sess is None:
         return
@@ -307,11 +283,27 @@ def _emit_invoke(name: str, n, dt, p) -> None:
     sess.emit("kernel_invoke", op=name,
               n=(int(n) if n is not None else None),
               dtype=(autotune.dtype_tag(dt) if dt is not None else None),
-              path=str(p),
+              path=path,
               tuning=(tuning.as_dict() if tuning is not None else None))
     sess.counter(
         "repro_kernel_invocations_total",
-        "kernel-registry executions by op/path").inc(op=name, path=str(p))
+        "kernel-registry executions by op/path").inc(op=name, path=path)
+
+
+def _oracle_fallback(name: str, p, mode: str) -> None:
+    """A ``tile``/``interpret`` call whose shape the kernel does not fit
+    runs the fused oracle instead: warn once per op, or raise under
+    ``interpret_fallback="error"`` (a silent oracle would pass for the
+    kernel in a measurement)."""
+    msg = (f"{name}: the call's shape does not fit the Pallas kernel's "
+           f"blocks; path={str(p)!r} runs the fused oracle instead")
+    if mode == "error":
+        raise RuntimeError(
+            f"{msg}, which this policy's interpret_fallback='error' forbids")
+    if mode == "warn":
+        from repro.core import policy as kpolicy
+
+        kpolicy.warn_once(f"oracle:{name}", msg, UserWarning, stacklevel=4)
 
 
 def pallas_op(name: str, *args: Any, policy: Any = None,
@@ -334,11 +326,17 @@ def pallas_op(name: str, *args: Any, policy: Any = None,
     op = get_op(name)
     n, dt = _call_shape(name, args)
     path = _merge_use_pallas(path, use_pallas)
-    p = kpolicy.as_policy(policy).resolve(op=name, n=n, dtype=dt,
-                                          level="kernel", explicit=path)
+    pol = kpolicy.as_policy(policy)
+    p = pol.resolve(op=name, n=n, dtype=dt, level="kernel", explicit=path)
+    ran = str(p)
+    if p in ("tile_tpu", "interpret") and op.fits is not None \
+            and not op.fits(*args, tuning=getattr(p, "tuning", None),
+                            **kwargs):
+        _oracle_fallback(name, p, pol.interpret_fallback)
+        ran = "oracle"
     if _obs.ACTIVE is not None:   # off by default; one global load
-        _emit_invoke(name, n, dt, p)
-    if p == "fused":
+        _emit_invoke(name, n, dt, p, ran)
+    if p == "fused" or ran == "oracle":
         return op.fused(*args, **kwargs)
     if op.knobs:
         kwargs["tuning"] = getattr(p, "tuning", None)

@@ -39,6 +39,11 @@ import jax.numpy as jnp
 LANES = 128      # TPU MXU/VPU lane count — the systolic-array edge
 SUBLANES = 8     # TPU f32 sublane tile (min second-to-last dim)
 MMA_TILE = 16    # GPU tensor-core MMA fragment edge (WMMA 16x16x16)
+# The reduce/scan/SSD kernels and their XLA combines contract f32 operands
+# at full f32 precision: the TPU default (Mosaic's and XLA's alike) is one
+# bf16 pass, which rounds every element, and every carried running sum, to
+# 8 mantissa bits.
+MXU_PRECISION = jax.lax.Precision.HIGHEST
 
 # KV-cache page height for the paged serving pool (serving/kvpool.py):
 # a power-of-two multiple of SUBLANES so a page is a whole number of

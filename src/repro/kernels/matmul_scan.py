@@ -51,7 +51,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels import backend
-from repro.kernels.layout import LANES, SUBLANES
+from repro.kernels.layout import LANES, MXU_PRECISION, SUBLANES
 
 
 # ---------------------------------------------------------------------------
@@ -121,7 +121,7 @@ def tree_scan(t: jax.Array, *, radix: int, fan_in: int) -> jax.Array:
     if m <= fan_in:
         return jax.lax.dot_general(
             t, upper_tri_ones(m), (((t.ndim - 1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            precision=MXU_PRECISION, preferred_element_type=jnp.float32)
     groups = -(-m // radix)
     pad = groups * radix - m
     if pad:  # zero-padding is the scan identity: the tail never leaks back
@@ -129,13 +129,13 @@ def tree_scan(t: jax.Array, *, radix: int, fan_in: int) -> jax.Array:
     tg = t.reshape(*t.shape[:-1], groups, radix)
     local = jax.lax.dot_general(                       # upsweep: @ U_radix
         tg, upper_tri_ones(radix), (((tg.ndim - 1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=MXU_PRECISION, preferred_element_type=jnp.float32)
     carry = tree_scan(local[..., -1], radix=radix, fan_in=fan_in)
     exc = _shift_right(carry, -1)
     local = local + jax.lax.dot_general(               # downsweep: @ B_radix
         exc[..., None], broadcast_row(radix),
         (((exc.ndim,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=MXU_PRECISION, preferred_element_type=jnp.float32)
     return local.reshape(*local.shape[:-2], groups * radix)[..., :m]
 
 
@@ -159,7 +159,7 @@ def tree_weighted(logp: jax.Array, t: jax.Array, *, radix: int,
     t = t.astype(jnp.float32)
     m = logp.shape[-1]
     if m <= fan_in:
-        return jnp.matmul(jnp.exp(segsum(logp)), t)
+        return jnp.matmul(jnp.exp(segsum(logp)), t, precision=MXU_PRECISION)
     groups = -(-m // radix)
     pad = groups * radix - m
     if pad:
@@ -167,13 +167,14 @@ def tree_weighted(logp: jax.Array, t: jax.Array, *, radix: int,
         t = jnp.pad(t, [(0, 0)] * (t.ndim - 2) + [(0, pad), (0, 0)])
     lg = logp.reshape(*logp.shape[:-1], groups, radix)
     tg = t.reshape(*t.shape[:-2], groups, radix, t.shape[-1])
-    local = jnp.matmul(jnp.exp(segsum(lg)), tg)        # (..., g, radix, F)
+    local = jnp.matmul(jnp.exp(segsum(lg)), tg,        # (..., g, radix, F)
+                       precision=MXU_PRECISION)
     carry = tree_weighted(jnp.sum(lg, axis=-1), local[..., -1, :],
                           radix=radix, fan_in=fan_in)
     exc = _shift_right(carry, -2)                      # (..., g, F)
     cum = jax.lax.dot_general(                         # within-group Λ
         lg, upper_tri_ones(radix), (((lg.ndim - 1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=MXU_PRECISION, preferred_element_type=jnp.float32)
     local = local + jnp.exp(cum)[..., None] * exc[..., None, :]
     return local.reshape(
         *local.shape[:-3], groups * radix, local.shape[-1])[..., :m, :]
@@ -189,6 +190,7 @@ def _local_scan_kernel(x_ref, o_ref):
     bn = a.shape[1]
     o_ref[...] = jax.lax.dot_general(
         a, upper_tri_ones(bn, a.dtype), (((1,), (0,)), ((), ())),
+        precision=MXU_PRECISION,
         preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
@@ -223,12 +225,13 @@ def matmul_local_scan(x: jax.Array, *, block_s: int, block_n: int,
 
 
 def _local_weighted_kernel(x_ref, lam_ref, o_ref, *, q: int):
-    lam = lam_ref[...].astype(jnp.float32)             # (1, q)
-    x = x_ref[...].astype(jnp.float32)                 # (1, q)
+    lam = lam_ref[0].astype(jnp.float32)               # (1, q)
+    x = x_ref[0].astype(jnp.float32)                   # (1, q)
     # Λ = λ @ U (matmul-form cumulative log decay), then the
     # 1-semiseparable mask M[t, τ] = exp(Λ_t − Λ_τ) for τ ≤ t
     cum = jax.lax.dot_general(
         lam, upper_tri_ones(q), (((1,), (0,)), ((), ())),
+        precision=MXU_PRECISION,
         preferred_element_type=jnp.float32)            # (1, q)
     rows = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
@@ -236,8 +239,9 @@ def _local_weighted_kernel(x_ref, lam_ref, o_ref, *, q: int):
     m = jnp.where(rows >= cols, jnp.exp(diff), 0.0)    # (q, q)
     # y_t = Σ_τ M[t, τ] x_τ, laid out (1, q): contract x's lane axis
     # against M's τ axis
-    o_ref[...] = jax.lax.dot_general(
+    o_ref[0] = jax.lax.dot_general(
         x, m, (((1,), (1,)), ((), ())),
+        precision=MXU_PRECISION,
         preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
@@ -252,27 +256,28 @@ def matmul_local_weighted(x: jax.Array, lam: jax.Array, *, q: int,
         raise ValueError(f"block q={q} must be a multiple of {LANES}")
     if n % q:
         raise ValueError(f"n={n} must be a multiple of q={q}")
+    # one row per block through a (rows, 1, n) view: a (1, q) block of a
+    # 2-D array breaks Mosaic's 8-sublane rule, a (1, 1, q) block of the
+    # 3-D view matches the array's unit dim
+    block = pl.BlockSpec((1, 1, q), lambda i, j: (i, 0, j))
     return pl.pallas_call(
         functools.partial(_local_weighted_kernel, q=q),
         grid=(rows, n // q),
-        in_specs=[
-            pl.BlockSpec((1, q), lambda i, j: (i, j)),
-            pl.BlockSpec((1, q), lambda i, j: (i, j)),
-        ],
-        out_specs=pl.BlockSpec((1, q), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((rows, n), jnp.float32),
+        in_specs=[block, block],
+        out_specs=block,
+        out_shape=jax.ShapeDtypeStruct((rows, 1, n), jnp.float32),
         compiler_params=backend.compiler_params(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,
         name="matmul_local_weighted",
-    )(x, lam)
+    )(x[:, None, :], lam[:, None, :])[:, 0, :]
 
 
 def _local_ssd_kernel(xdt_ref, lam_ref, b_ref, c_ref, y_ref, s_ref, *,
                       q: int):
     xdt = xdt_ref[0].astype(jnp.float32)               # (q, P)
-    lam = lam_ref[...].astype(jnp.float32)             # (1, q)
+    lam = lam_ref[0].astype(jnp.float32)               # (1, q)
     bmat = b_ref[0].astype(jnp.float32)                # (q, N)
     cmat = c_ref[0].astype(jnp.float32)                # (q, N)
 
@@ -280,6 +285,7 @@ def _local_ssd_kernel(xdt_ref, lam_ref, b_ref, c_ref, y_ref, s_ref, *,
     cols = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
     cum = jax.lax.dot_general(
         lam, upper_tri_ones(q), (((1,), (0,)), ((), ())),
+        precision=MXU_PRECISION,
         preferred_element_type=jnp.float32)            # (1, q)
     total = jnp.sum(lam)
 
@@ -289,15 +295,17 @@ def _local_ssd_kernel(xdt_ref, lam_ref, b_ref, c_ref, y_ref, s_ref, *,
     m = jnp.where(rows >= cols, jnp.exp(diff), 0.0)
     cb = jax.lax.dot_general(
         cmat, bmat, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=MXU_PRECISION, preferred_element_type=jnp.float32)
     y_ref[0] = jax.lax.dot_general(
         cb * m, xdt, (((1,), (0,)), ((), ())),
+        precision=MXU_PRECISION,
         preferred_element_type=jnp.float32).astype(y_ref.dtype)
 
     # Per-chunk state contribution S = (B ∘ w)ᵀ @ (dt∘X), w_τ = exp(Σλ − Λ_τ)
     bw = bmat * jnp.exp(total - cum[0])[:, None]
     s_ref[0] = jax.lax.dot_general(
         bw, xdt, (((0,), (0,)), ((), ())),
+        precision=MXU_PRECISION,
         preferred_element_type=jnp.float32).astype(s_ref.dtype)
 
 
@@ -327,7 +335,7 @@ def matmul_local_ssd(
         grid=(bh, nchunks),
         in_specs=[
             pl.BlockSpec((1, q, hdim), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, q), lambda i, j: (i, j)),
+            pl.BlockSpec((1, 1, q), lambda i, j: (i, 0, j)),   # (BH, 1, L)
             pl.BlockSpec((1, q, nstate), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, q, nstate), lambda i, j: (i, j, 0)),
         ],
@@ -344,4 +352,4 @@ def matmul_local_ssd(
         ),
         interpret=interpret,
         name="matmul_local_ssd",
-    )(xdt, lam, b, c)
+    )(xdt, lam[:, None, :], b, c)

@@ -212,10 +212,13 @@ def weighted_scan(x: jax.Array, log_a: jax.Array, *, policy=None,
 # rmsnorm (differentiable: all paths share one custom VJP)
 
 
+def _rmsnorm_fits(x, w, *, tuning=None, **_) -> bool:
+    """The kernel is lane-strict: d must be a multiple of 128."""
+    return x.shape[-1] % LANES == 0
+
+
 def _rmsnorm_tile_fwd(x, w, eps, interpret, tuning):
     lead, d = x.shape[:-1], x.shape[-1]
-    if d % LANES:  # kernel is lane-strict; unaligned d -> oracle (the
-        return ref.rmsnorm_ref(x, w, eps=eps)  # same idiom as attention)
     rb = layout.fit_block(_nrows(lead), _knob(tuning, "row_block", "rmsnorm"),
                           SUBLANES)
     flat = _pad_axis(x.reshape(-1, d), 0, rb)
@@ -345,7 +348,8 @@ def _ssd_tile_logdepth(
     cdec = (cc.reshape(bh, nchunks, q, n_pad)
             * jnp.exp(jnp.cumsum(lg, axis=-1))[..., None])
     y = (y.reshape(bh, nchunks, q, p_pad)
-         + jnp.einsum("bjqn,bjnp->bjqp", cdec, h_exc)
+         + jnp.einsum("bjqn,bjnp->bjqp", cdec, h_exc,
+                      precision=layout.MXU_PRECISION)
          ).reshape(bh, l_pad, p_pad)
     state = h_inc[:, -1].reshape(bh, n_pad, p_pad)
     return ssd_unfold(y, state, bsz=bsz, nheads=nheads, seqlen=seqlen,
@@ -366,20 +370,28 @@ def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
 # attention
 
 
+def _attention_blocks(q: jax.Array, k: jax.Array, tuning) -> tuple:
+    # block_q rides the sublanes (the kernel accepts any 8-multiple);
+    # block_k is the lane dim of the score tile and stays a 128-multiple
+    bq = layout.fit_block(q.shape[2], _knob(tuning, "block_q", "attention"),
+                          SUBLANES)
+    bk = layout.fit_block(k.shape[2], _knob(tuning, "block_k", "attention"),
+                          LANES)
+    return bq, bk
+
+
+def _attention_fits(q, k, v, *, tuning=None, **_) -> bool:
+    """The kernel is block-strict: both lengths must fill whole blocks."""
+    bq, bk = _attention_blocks(q, k, tuning)
+    return q.shape[2] % bq == 0 and k.shape[2] % bk == 0
+
+
 def _attention_tile(
     q: jax.Array, k: jax.Array, v: jax.Array, *,
     causal: bool = True, window: int | None = None,
     scale: float | None = None, tuning=None, interpret: bool = False,
 ) -> jax.Array:
-    lq, lk = q.shape[2], k.shape[2]
-    # block_q rides the sublanes (the kernel accepts any 8-multiple);
-    # block_k is the lane dim of the score tile and stays a 128-multiple
-    bq = layout.fit_block(lq, _knob(tuning, "block_q", "attention"),
-                          SUBLANES)
-    bk = layout.fit_block(lk, _knob(tuning, "block_k", "attention"), LANES)
-    if lq % bq or lk % bk:  # kernel is block-strict; unaligned -> oracle
-        return ref.flash_attention_ref(q, k, v, causal=causal, window=window,
-                                       scale=scale)
+    bq, bk = _attention_blocks(q, k, tuning)
     return _require_pallas(_flash_kernel, "attention")(
         q, k, v, causal=causal, window=window, scale=scale,
         block_q=bq, block_k=bk, interpret=interpret)
@@ -469,7 +481,8 @@ backend.register_op("weighted_scan",
 # rmsnorm carries its own custom VJP (all paths share it) — no wrapper
 backend.register_op("rmsnorm", tile=_rmsnorm_tile, fused=_rmsnorm_fused,
                     tile_gpu=(_rmsnorm_tile_gpu if triton_ops is not None
-                              else None))
+                              else None),
+                    fits=_rmsnorm_fits)
 backend.register_op("ssd_scan",
                     tile=_diff_via_ref(_ssd_tile, ref.ssd_scan_ref),
                     fused=ref.ssd_scan_ref,
@@ -485,4 +498,5 @@ backend.register_op("attention",
                                        ref.flash_attention_ref),
                     fused=ref.flash_attention_ref,
                     tile_gpu=_diff_via_ref(_gpu_entry("attention_tile_gpu"),
-                                           ref.flash_attention_ref))
+                                           ref.flash_attention_ref),
+                    fits=_attention_fits)

@@ -32,7 +32,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import backend
-from repro.kernels.layout import LANES, default_tuning
+from repro.kernels.layout import LANES, MXU_PRECISION, default_tuning
 
 
 def _ssd_kernel(xdt_ref, lam_ref, b_ref, c_ref, y_ref, state_ref, h_ref,
@@ -44,7 +44,7 @@ def _ssd_kernel(xdt_ref, lam_ref, b_ref, c_ref, y_ref, state_ref, h_ref,
         h_ref[...] = jnp.zeros_like(h_ref)
 
     xdt = xdt_ref[0].astype(jnp.float32)             # (q, P)  dt-weighted input
-    lam = lam_ref[...].astype(jnp.float32)           # (1, q)  log decays
+    lam = lam_ref[0].astype(jnp.float32)             # (1, q)  log decays
     bmat = b_ref[0].astype(jnp.float32)              # (q, N)
     cmat = c_ref[0].astype(jnp.float32)              # (q, N)
 
@@ -53,7 +53,8 @@ def _ssd_kernel(xdt_ref, lam_ref, b_ref, c_ref, y_ref, state_ref, h_ref,
     u = (rows <= cols).astype(jnp.float32)
     # Λ = λ @ U : inclusive cumulative log-decay, matmul-form (paper's A·U).
     cum = jax.lax.dot_general(
-        lam, u, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        lam, u, (((1,), (0,)), ((), ())),
+        precision=MXU_PRECISION, preferred_element_type=jnp.float32
     )                                                # (1, q)
     total = jnp.sum(lam)                             # Σ_chunk λ (scalar)
 
@@ -63,17 +64,19 @@ def _ssd_kernel(xdt_ref, lam_ref, b_ref, c_ref, y_ref, state_ref, h_ref,
 
     # Intra-chunk: Y = ((C Bᵀ) ∘ M) @ (dt∘X)
     cb = jax.lax.dot_general(
-        cmat, bmat, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        cmat, bmat, (((1,), (1,)), ((), ())),
+        precision=MXU_PRECISION, preferred_element_type=jnp.float32
     )                                                # (q, q)
     y = jax.lax.dot_general(
-        cb * m, xdt, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        cb * m, xdt, (((1,), (0,)), ((), ())),
+        precision=MXU_PRECISION, preferred_element_type=jnp.float32
     )                                                # (q, P)
 
     # Inter-chunk: Y += (C ∘ exp(Λ)) @ H_prev
     cdec = cmat * jnp.exp(cum[0])[:, None]           # (q, N)
     y += jax.lax.dot_general(
         cdec, h_ref[...], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
+        precision=MXU_PRECISION, preferred_element_type=jnp.float32,
     )
     y_ref[0] = y.astype(y_ref.dtype)
 
@@ -81,7 +84,8 @@ def _ssd_kernel(xdt_ref, lam_ref, b_ref, c_ref, y_ref, state_ref, h_ref,
     w = jnp.exp(total - cum[0])                      # (q,)
     bw = bmat * w[:, None]                           # (q, N)
     s_new = jax.lax.dot_general(
-        bw, xdt, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        bw, xdt, (((0,), (0,)), ((), ())),
+        precision=MXU_PRECISION, preferred_element_type=jnp.float32
     )                                                # (N, P)
     h_ref[...] = jnp.exp(total) * h_ref[...] + s_new
 
@@ -113,12 +117,15 @@ def ssd_chunk_scan(
     if seqlen % q:
         raise ValueError(f"L={seqlen} must be a multiple of {q}")
     nchunks = seqlen // q
+    # lam rides as a (BH, 1, L) view: a (1, q) block of a 2-D (BH, L)
+    # array breaks Mosaic's 8-sublane rule, a (1, 1, q) block of the 3-D
+    # view matches the array's unit dim
     return pl.pallas_call(
         functools.partial(_ssd_kernel, nchunks=nchunks, q=q),
         grid=(bh, nchunks),
         in_specs=[
             pl.BlockSpec((1, q, hdim), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, q), lambda i, j: (i, j)),
+            pl.BlockSpec((1, 1, q), lambda i, j: (i, 0, j)),
             pl.BlockSpec((1, q, nstate), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, q, nstate), lambda i, j: (i, j, 0)),
         ],
@@ -136,4 +143,4 @@ def ssd_chunk_scan(
         ),
         interpret=interpret,
         name="ssd_chunk_scan",
-    )(xdt, lam, b, c)
+    )(xdt, lam[:, None, :], b, c)

@@ -29,7 +29,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import backend
-from repro.kernels.layout import LANES, SUBLANES, default_tuning
+from repro.kernels.layout import LANES, MXU_PRECISION, SUBLANES, default_tuning
 
 
 def _reduce_kernel(x_ref, o_ref, acc_ref, *, nchunks: int):
@@ -44,12 +44,13 @@ def _reduce_kernel(x_ref, o_ref, acc_ref, *, nchunks: int):
     # every result row holds the column sums; row 0 is the paper's V row.
     p = jnp.ones((SUBLANES, a.shape[0]), a.dtype)
     acc_ref[...] += jax.lax.dot_general(
-        p, a, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        p, a, (((1,), (0,)), ((), ())),
+        precision=MXU_PRECISION, preferred_element_type=jnp.float32
     )
 
     @pl.when(j == nchunks - 1)
     def _store():
-        o_ref[...] = acc_ref[0, :].astype(o_ref.dtype)
+        o_ref[...] = acc_ref[0:1, :].astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -81,12 +82,14 @@ def tcu_segmented_reduce_tn(xt: jax.Array, *, block_s: int | None = None,
         functools.partial(_reduce_kernel, nchunks=nchunks),
         grid=(s // block_s, nchunks),
         in_specs=[pl.BlockSpec((block_n, block_s), lambda i, j: (j, i))],
-        out_specs=pl.BlockSpec((block_s,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((s,), jnp.float32),
+        # the result is a (1, s) row: a 1-D (block_s,) block does not match
+        # the T(1024) tiling XLA gives a long f32 vector on TPU
+        out_specs=pl.BlockSpec((1, block_s), lambda i, j: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, s), jnp.float32),
         scratch_shapes=[pltpu.VMEM((SUBLANES, block_s), jnp.float32)],
         compiler_params=backend.compiler_params(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
         name="tcu_segmented_reduce",
-    )(xt)
+    )(xt)[0]

@@ -26,7 +26,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import backend
-from repro.kernels.layout import LANES, SUBLANES, default_tuning
+from repro.kernels.layout import LANES, MXU_PRECISION, SUBLANES, default_tuning
 
 
 def _scan_kernel(x_ref, o_ref, carry_ref, *, nchunks: int):
@@ -42,7 +42,8 @@ def _scan_kernel(x_ref, o_ref, carry_ref, *, nchunks: int):
     cols = jax.lax.broadcasted_iota(jnp.int32, (bn, bn), 1)
     u = (rows <= cols).astype(a.dtype)               # upper-triangular ones
     au = jax.lax.dot_general(
-        a, u, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        a, u, (((1,), (0,)), ((), ())),
+        precision=MXU_PRECISION, preferred_element_type=jnp.float32
     ) + carry_ref[...]
     o_ref[...] = au.astype(o_ref.dtype)
 
@@ -51,7 +52,8 @@ def _scan_kernel(x_ref, o_ref, carry_ref, *, nchunks: int):
         # Broadcast(LastColumn(R)): E has ones only in the last row.
         e = (rows == bn - 1).astype(jnp.float32)
         carry_ref[...] = jax.lax.dot_general(
-            au, e, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            au, e, (((1,), (0,)), ((), ())),
+            precision=MXU_PRECISION, preferred_element_type=jnp.float32
         )
 
 

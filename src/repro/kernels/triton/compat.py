@@ -1,17 +1,13 @@
-"""Version shim for the Pallas-Triton (GPU) lowering.
+"""The Pallas-Triton (GPU) lowering's single import site.
 
 This is the ONLY module in the repo allowed to import
 ``jax.experimental.pallas.triton`` — the same discipline the raw
 compiler-params guard enforces for ``pltpu`` (a grep-guard test checks it).
-Like the TPU side, the class name drifts across JAX releases
-(``TritonCompilerParams`` on 0.4.x, ``CompilerParams`` on newer trees), so
-every Triton kernel builds its params through :func:`compiler_params` here
+Every Triton kernel builds its params through :func:`compiler_params` here
 (usually via ``repro.kernels.backend.compiler_params(backend="gpu", ...)``).
 """
 from __future__ import annotations
 
-import dataclasses
-import inspect
 from typing import Any
 
 
@@ -30,34 +26,7 @@ def available() -> bool:
         return False
 
 
-def compiler_params_cls() -> type:
-    """The Pallas-Triton compiler-params class under whichever name the
-    installed JAX uses (``CompilerParams`` preferred, ``TritonCompilerParams``
-    on 0.4.x)."""
-    plgpu = _plgpu()
-    for name in ("CompilerParams", "TritonCompilerParams"):
-        cls = getattr(plgpu, name, None)
-        if cls is not None:
-            return cls
-    import jax
-
-    raise RuntimeError(
-        f"jax {jax.__version__}: no Pallas-Triton compiler-params class "
-        "found; the version shim in repro.kernels.triton.compat needs a new "
-        "spelling"
-    )
-
-
-def _accepted_fields(cls: type) -> set[str]:
-    if dataclasses.is_dataclass(cls):
-        return {f.name for f in dataclasses.fields(cls)}
-    return set(inspect.signature(cls).parameters)
-
-
 def compiler_params(**kwargs: Any):
-    """Construct Triton compiler params portably, dropping fields the
-    installed JAX doesn't know (including TPU-only knobs such as
-    ``dimension_semantics`` — GPU grids are always parallel)."""
-    cls = compiler_params_cls()
-    fields = _accepted_fields(cls)
-    return cls(**{k: v for k, v in kwargs.items() if k in fields})
+    """``plgpu.CompilerParams`` (``num_warps``/``num_stages``); an unknown
+    field raises."""
+    return _plgpu().CompilerParams(**kwargs)

@@ -48,8 +48,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float, causal: bool,
     def body(jk, carry):
         m_prev, l_prev, acc = carry
         ksl = (pl.dslice(jk * bk, bk), slice(None))
-        k = pl.load(k_ref, ksl).astype(jnp.float32)  # (BK, D)
-        v = pl.load(v_ref, ksl).astype(jnp.float32)  # (BK, D)
+        k = k_ref[ksl].astype(jnp.float32)  # (BK, D)
+        v = v_ref[ksl].astype(jnp.float32)  # (BK, D)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale                                    # (BQ, BK)

@@ -37,9 +37,7 @@ def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float, d: int,
     ones = jnp.ones((block_d, TILE), jnp.float32)
 
     def ssq_body(k, acc):
-        xx = pl.load(
-            x_ref, (slice(None), pl.dslice(k * block_d, block_d))
-        ).astype(jnp.float32)
+        xx = x_ref[:, pl.dslice(k * block_d, block_d)].astype(jnp.float32)
         # (x∘x) @ 1 : matmul-form row reduction, lanes replicated
         return acc + jax.lax.dot_general(
             xx * xx, ones, (((1,), (0,)), ((), ())),
@@ -53,9 +51,9 @@ def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float, d: int,
 
     def norm_body(k, _):
         sl = (slice(None), pl.dslice(k * block_d, block_d))
-        xx = pl.load(x_ref, sl).astype(jnp.float32)
-        w = pl.load(w_ref, (slice(None), sl[1])).astype(jnp.float32)  # (1, BD)
-        pl.store(o_ref, sl, (xx * rstd * w).astype(o_ref.dtype))
+        xx = x_ref[sl].astype(jnp.float32)
+        w = w_ref[:, sl[1]].astype(jnp.float32)  # (1, BD)
+        o_ref[sl] = (xx * rstd * w).astype(o_ref.dtype)
         return 0
 
     jax.lax.fori_loop(0, nchunks, norm_body, 0)

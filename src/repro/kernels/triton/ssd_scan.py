@@ -39,10 +39,10 @@ def _ssd_kernel(xdt_ref, lam_ref, b_ref, c_ref, y_ref, state_ref, *,
 
     def body(jc, h):
         tsl = pl.dslice(jc * q, q)
-        xdt = pl.load(xdt_ref, (tsl, slice(None))).astype(jnp.float32)  # (Q,P)
-        lam = pl.load(lam_ref, (tsl,)).astype(jnp.float32)              # (Q,)
-        bmat = pl.load(b_ref, (tsl, slice(None))).astype(jnp.float32)   # (Q,N)
-        cmat = pl.load(c_ref, (tsl, slice(None))).astype(jnp.float32)   # (Q,N)
+        xdt = xdt_ref[tsl, :].astype(jnp.float32)  # (Q,P)
+        lam = lam_ref[tsl].astype(jnp.float32)     # (Q,)
+        bmat = b_ref[tsl, :].astype(jnp.float32)   # (Q,N)
+        cmat = c_ref[tsl, :].astype(jnp.float32)   # (Q,N)
 
         # Λ = λ @ U in matmul form, on a 16-row fragment (rows identical).
         lam16 = jnp.broadcast_to(lam[None, :], (TILE, q))
@@ -68,7 +68,7 @@ def _ssd_kernel(xdt_ref, lam_ref, b_ref, c_ref, y_ref, state_ref, *,
         y += jax.lax.dot_general(
             cmat * jnp.exp(cum)[:, None], h, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        pl.store(y_ref, (tsl, slice(None)), y)
+        y_ref[tsl, :] = y
 
         # State update: H = exp(Σλ)·H + (B ∘ w)ᵀ @ (dt∘X), w_τ = exp(Σλ − Λ_τ)
         bw = bmat * jnp.exp(total - cum)[:, None]            # (Q, N)

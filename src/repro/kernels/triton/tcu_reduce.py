@@ -41,7 +41,7 @@ def _reduce_kernel(x_ref, o_ref, *, block_s: int, block_n: int, nchunks: int):
     ones = jnp.ones((block_n, TILE), jnp.float32)
 
     def body(k, acc):
-        a = pl.load(x_ref, (slice(None), pl.dslice(k * block_n, block_n)))
+        a = x_ref[:, pl.dslice(k * block_n, block_n)]
         # A @ 1 : every output lane holds the row (segment) sums.
         return acc + jax.lax.dot_general(
             a.astype(jnp.float32), ones, (((1,), (0,)), ((), ())),

@@ -39,11 +39,11 @@ def _scan_kernel(x_ref, o_ref, *, block_s: int, block_n: int, nchunks: int):
 
     def body(k, carry):
         sl = (slice(None), pl.dslice(k * block_n, block_n))
-        a = pl.load(x_ref, sl).astype(jnp.float32)
+        a = x_ref[sl].astype(jnp.float32)
         au = jax.lax.dot_general(
             a, u, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) + carry
-        pl.store(o_ref, sl, au)
+        o_ref[sl] = au
         # Broadcast(LastColumn(R)) as R @ E — stays on the tensor core.
         return jax.lax.dot_general(
             au, e, (((1,), (0,)), ((), ())),

@@ -33,6 +33,7 @@ from repro import configs
 from repro.configs.common import SHAPE_TABLE, make_cell
 from repro.launch import mesh as mesh_lib
 from repro.launch.hlo_analysis import analyse, roofline_terms
+from repro.launch.peaks import peaks_for
 from repro.models import build
 from repro.models.common import partition_specs, shape_structs
 from repro.optim import OptConfig
@@ -174,9 +175,12 @@ def run_cell(arch: str, shape: str, mesh_name: str, out_dir: str, *,
     hlo = compiled.as_text()
     h = analyse(hlo)
     n_chips = mesh.devices.size
+    # the production meshes are v5e pods; the placeholder host devices
+    # stand in for them
+    v5e = peaks_for("TPU v5 lite")
     terms = roofline_terms(
-        h, peak_flops=mesh_lib.PEAK_FLOPS_BF16, hbm_bw=mesh_lib.HBM_BW,
-        ici_bw=mesh_lib.ICI_BW)
+        h, peak_flops=v5e.bf16_flops, hbm_bw=v5e.hbm_bytes_s,
+        ici_bw=v5e.ici_bytes_s)
     mf = model_flops(info["bundle"], info["cell"])
 
     rec = {

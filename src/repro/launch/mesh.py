@@ -23,14 +23,6 @@ from repro.parallel.compat import make_mesh
 from repro.parallel.mesh_context import MeshContext
 from repro.parallel.sharding import Rules
 
-# Hardware constants (TPU v5e) used by the roofline analyser.
-PEAK_FLOPS_BF16 = 197e12          # per chip
-HBM_BW = 819e9                    # bytes/s per chip
-ICI_BW = 50e9                     # bytes/s per link (~per axis direction)
-VMEM_BYTES = 16 * 2 ** 20
-HBM_BYTES = 16 * 2 ** 30
-
-
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")

@@ -10,6 +10,11 @@ import glob
 import json
 import os
 
+from repro.launch.peaks import PEAKS
+
+# the dry-run's production meshes are v5e pods
+_V5E = PEAKS["TPU v5 lite"]
+
 ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "..",
                                     ".."))
 ART = os.path.join(ROOT, "artifacts", "dryrun")
@@ -78,7 +83,7 @@ def roofline_table() -> str:
     for key in sorted(single):
         r = single[key]
         t = r["roofline"]
-        mf_s = r["model_flops_per_chip"] / 197e12
+        mf_s = r["model_flops_per_chip"] / _V5E.bf16_flops
         frac = mf_s / max(t["bound_s"], 1e-30)
         doms[t["dominant"]] = doms.get(t["dominant"], 0) + 1
         worst.append((frac, key))

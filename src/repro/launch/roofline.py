@@ -20,6 +20,11 @@ import glob
 import json
 import os
 
+from repro.launch.peaks import PEAKS
+
+# the dry-run's production meshes are v5e pods
+_V5E = PEAKS["TPU v5 lite"]
+
 LEVERS = {
     "compute_s": "raise MXU occupancy: fewer rematerialised dots, "
                  "larger fused matmul tiles",
@@ -48,7 +53,7 @@ def fmt_row(rec: dict) -> str:
         for k in ("compute_s", "memory_s", "collective_s")
     }
     # roofline fraction: useful model compute time / bound time
-    mf_s = rec["model_flops_per_chip"] / 197e12
+    mf_s = rec["model_flops_per_chip"] / _V5E.bf16_flops
     roofline_frac = mf_s / max(r["bound_s"], 1e-30)
     return (f"| {rec['arch']} | {rec['shape']} | {r['compute_s']:.3e} | "
             f"{r['memory_s']:.3e} | {r['collective_s']:.3e} | "
@@ -75,7 +80,7 @@ def main() -> None:
     print()
     doms: dict[str, int] = {}
     worst = sorted(
-        recs, key=lambda r: (r["model_flops_per_chip"] / 197e12)
+        recs, key=lambda r: (r["model_flops_per_chip"] / _V5E.bf16_flops)
         / max(r["roofline"]["bound_s"], 1e-30))
     for rec in recs:
         doms[rec["roofline"]["dominant"]] = doms.get(
@@ -85,7 +90,7 @@ def main() -> None:
         print("worst roofline fractions:")
         for rec in worst[:5]:
             r = rec["roofline"]
-            mf_s = rec["model_flops_per_chip"] / 197e12
+            mf_s = rec["model_flops_per_chip"] / _V5E.bf16_flops
             print(f"  {rec['arch']:24s} {rec['shape']:12s} "
                   f"frac={mf_s / max(r['bound_s'], 1e-30):.4f} "
                   f"dom={r['dominant']} lever: {LEVERS[r['dominant']]}")

@@ -18,6 +18,7 @@ import numpy as np
 
 from repro import configs
 from repro.checkpoint import ckpt
+from repro.launch import compile_cache
 from repro.models import build
 from repro.models.common import init_params
 from repro.obs import cli as obs_cli
@@ -77,6 +78,7 @@ def main() -> None:
                     help="deprecated alias for --policy <path-label>")
     obs_cli.add_obs_args(ap)
     args = ap.parse_args()
+    compile_cache.enable()
 
     pol = kpolicy.policy_from_cli(args.policy, args.kernel_path,
                                   "deprecated:launch.serve.kernel_path",

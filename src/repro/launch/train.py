@@ -34,6 +34,7 @@ from repro import configs
 from repro.checkpoint import ckpt
 from repro.configs.common import SMOKE_BATCH, SMOKE_SEQ
 from repro.data.pipeline import DataConfig, SyntheticLMPipeline
+from repro.launch import compile_cache
 from repro.models import build
 from repro.obs import cli as obs_cli
 from repro.obs import profiling as _prof
@@ -97,6 +98,7 @@ def main() -> None:
                     help="deprecated alias for --policy <path-label>")
     obs_cli.add_obs_args(ap)
     args = ap.parse_args()
+    compile_cache.enable()
 
     pol = kpolicy.policy_from_cli(args.policy, args.kernel_path,
                                   "deprecated:launch.train.kernel_path",

@@ -1,35 +1,27 @@
-"""Version shims for the mesh/sharding surface, sibling of
-``repro.kernels.backend`` (which shims the Pallas surface).
+"""The mesh/sharding entry points every caller in this repo goes through,
+sibling of ``repro.kernels.backend`` (which wraps the Pallas surface).
 
-Covers the renames between jax 0.4.x and 0.6+:
-
-* ``shard_map``: ``jax.experimental.shard_map.shard_map`` → ``jax.shard_map``
-* ``jax.make_mesh(..., axis_types=...)``: the kwarg and the
-  ``jax.sharding.AxisType`` enum only exist on 0.6+ (where meshes default to
-  explicit sharding; ``Auto`` restores the 0.4.x behaviour every caller in
-  this repo assumes).
+* ``shard_map`` — ``jax.shard_map``. Callers whose bodies mix per-shard
+  and psum'd values built from matmul-form carries (``parallel.shard_ops``)
+  pass ``check_vma=False``: the varying-manual-axes checker cannot type
+  them.
+* :func:`make_mesh` — ``jax.make_mesh`` pinned to ``AxisType.Auto``
+  (JAX defaults new meshes to explicit sharding; every caller in this repo
+  assumes GSPMD-propagated, auto axes).
 """
 from __future__ import annotations
 
-import inspect
 from typing import Sequence
 
 import jax
 
-try:                                    # jax >= 0.6
-    shard_map = jax.shard_map
-except AttributeError:                  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # noqa: F401
+
+shard_map = jax.shard_map
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str],
               **kwargs) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` pinned to auto (0.4.x-style) axis semantics, with
-    unknown kwargs dropped on older JAX."""
-    params = inspect.signature(jax.make_mesh).parameters
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if ("axis_types" in params and "axis_types" not in kwargs
-            and axis_type is not None):
-        kwargs["axis_types"] = (axis_type.Auto,) * len(tuple(axis_names))
-    kwargs = {k: v for k, v in kwargs.items() if k in params}
+    """``jax.make_mesh`` with auto axis semantics on every axis."""
+    kwargs.setdefault(
+        "axis_types", (jax.sharding.AxisType.Auto,) * len(tuple(axis_names)))
     return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)

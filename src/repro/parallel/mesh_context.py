@@ -1,7 +1,7 @@
 """MeshContext — mesh + rules + process topology as one first-class object.
 
 PRs 1-6 built the pieces separately: ``Rules`` (logical-name sharding
-table), ``compat.make_mesh`` (version shim), and ad-hoc ``(mesh, rules)``
+table), ``compat.make_mesh`` (auto-axis mesh), and ad-hoc ``(mesh, rules)``
 pairs constructed at every launch site. Multi-host execution needs them to
 travel together, because three layers consult the same topology:
 

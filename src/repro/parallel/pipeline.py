@@ -66,9 +66,7 @@ def pipeline_apply(
         outq0 = jnp.zeros_like(xq_local)
         # mark the carries as stage-varying for shard_map's VMA tracking
         # (buf0 already varies through idx; outq0 is a plain zeros tensor)
-        pcast = getattr(jax.lax, "pcast", None)
-        if pcast is not None:
-            outq0 = pcast(outq0, (cfg.axis,), to="varying")
+        outq0 = jax.lax.pcast(outq0, (cfg.axis,), to="varying")
 
         def tick_step(state, tick):
             buf, outq = state

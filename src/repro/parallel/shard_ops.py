@@ -26,6 +26,9 @@ consults them; ``core.dispatch`` itself stays mesh-free.
 """
 from __future__ import annotations
 
+import dataclasses
+from typing import Callable
+
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding
@@ -35,6 +38,7 @@ from repro.core.distributed import (
     dist_exclusive_carry,
     weighted_exclusive_carry,
 )
+from repro.kernels.layout import MXU_PRECISION
 from repro.obs import runtime as _obs
 from repro.parallel.compat import shard_map
 from repro.parallel.mesh_context import (
@@ -42,8 +46,33 @@ from repro.parallel.mesh_context import (
     shard_local_scope,
 )
 
-__all__ = ["sharded_reduce", "sharded_scan", "sharded_weighted_scan",
-           "sharded_ssd"]
+__all__ = ["plan", "sharded_reduce", "sharded_scan",
+           "sharded_weighted_scan", "sharded_ssd"]
+
+
+@dataclasses.dataclass(frozen=True)
+class Plan:
+    """One routed call: ``fn(*args)`` is the shard_map program it runs
+    (``jax.jit(fn).lower(*args)`` shows its collectives)."""
+    op: str
+    fn: Callable
+    args: tuple
+    ctx: object
+    dim: int
+    axes: tuple
+
+    def run(self):
+        if _obs.ACTIVE is not None:
+            _emit_route(self.op, self.args[0], self.dim, self.ctx,
+                        self.axes)
+        return self.fn(*self.args)
+
+
+def plan(op: str, *args, **kwargs) -> "Plan | None":
+    """The :class:`Plan` a ``repro.ops`` call of ``op`` (``"reduce"``,
+    ``"scan"``, ``"weighted_scan"`` or ``"ssd"``) would run on these
+    arguments, or None when it falls back to plain dispatch."""
+    return _PLANS[op](*args, **kwargs)
 
 
 def _emit_route(op: str, x, dim: int, ctx, axes) -> None:
@@ -94,15 +123,11 @@ def _full_spec(spec, ndim: int) -> tuple:
     return spec + (None,) * (ndim - len(spec))
 
 
-def sharded_reduce(x, *, policy=None):
-    """Last-axis reduce of a sharded array: per-shard kernel + psum.
-    Returns None when the call should fall back to plain dispatch."""
+def _plan_reduce(x, *, policy=None):
     route = _routing_ctx(x, x.ndim - 1)
     if route is None:
         return None
     ctx, spec, axes = route
-    if _obs.ACTIVE is not None:
-        _emit_route("reduce", x, x.ndim - 1, ctx, axes)
     from repro.core import dispatch
 
     def body(xs):
@@ -110,14 +135,19 @@ def sharded_reduce(x, *, policy=None):
             part = dispatch.reduce(xs, policy=policy)
         return jax.lax.psum(part, axes)
 
-    return shard_map(body, mesh=ctx.mesh, in_specs=(P(*spec),),
-                     out_specs=P(*spec[:-1]), check_rep=False)(x)
+    fn = shard_map(body, mesh=ctx.mesh, in_specs=(P(*spec),),
+                   out_specs=P(*spec[:-1]), check_vma=False)
+    return Plan("reduce", fn, (x,), ctx, x.ndim - 1, axes)
 
 
-def sharded_scan(x, *, policy=None, exclusive: bool = False):
-    """Last-axis inclusive scan of a sharded array: per-shard kernel +
-    exclusive carry of shard totals (scan-then-propagate). The exclusive
-    variant needs a cross-shard element shift, so it falls back."""
+def sharded_reduce(x, *, policy=None):
+    """Last-axis reduce of a sharded array: per-shard kernel + psum.
+    Returns None when the call should fall back to plain dispatch."""
+    p = _plan_reduce(x, policy=policy)
+    return None if p is None else p.run()
+
+
+def _plan_scan(x, *, policy=None, exclusive: bool = False):
     if exclusive:
         return None
     route = _routing_ctx(x, x.ndim - 1)
@@ -126,8 +156,6 @@ def sharded_scan(x, *, policy=None, exclusive: bool = False):
     ctx, spec, axes = route
     if len(axes) != 1:
         return None  # multi-axis bucket sharding: fall back
-    if _obs.ACTIVE is not None:
-        _emit_route("scan", x, x.ndim - 1, ctx, axes)
     from repro.core import dispatch
 
     def body(xs):
@@ -136,13 +164,20 @@ def sharded_scan(x, *, policy=None, exclusive: bool = False):
         carry = dist_exclusive_carry(local[..., -1], axes[0])
         return local + carry[..., None]
 
-    return shard_map(body, mesh=ctx.mesh, in_specs=(P(*spec),),
-                     out_specs=P(*spec), check_rep=False)(x)
+    fn = shard_map(body, mesh=ctx.mesh, in_specs=(P(*spec),),
+                   out_specs=P(*spec), check_vma=False)
+    return Plan("scan", fn, (x,), ctx, x.ndim - 1, axes)
 
 
-def sharded_weighted_scan(x, log_a, *, policy=None):
-    """Last-axis decayed scan of a sharded array: per-shard kernel + the
-    1-semiseparable carry combine, propagated through prefix decays."""
+def sharded_scan(x, *, policy=None, exclusive: bool = False):
+    """Last-axis inclusive scan of a sharded array: per-shard kernel +
+    exclusive carry of shard totals (scan-then-propagate). The exclusive
+    variant needs a cross-shard element shift, so it falls back."""
+    p = _plan_scan(x, policy=policy, exclusive=exclusive)
+    return None if p is None else p.run()
+
+
+def _plan_weighted_scan(x, log_a, *, policy=None):
     route = _routing_ctx(x, x.ndim - 1)
     if route is None:
         return None
@@ -153,8 +188,6 @@ def sharded_weighted_scan(x, log_a, *, policy=None):
     if not isinstance(la_sh, NamedSharding) \
             or _full_spec(la_sh.spec, log_a.ndim) != spec:
         return None
-    if _obs.ACTIVE is not None:
-        _emit_route("weighted_scan", x, x.ndim - 1, ctx, axes)
     from repro.core import dispatch
 
     def body(xs, las):
@@ -165,19 +198,20 @@ def sharded_weighted_scan(x, log_a, *, policy=None):
         prefix = jnp.cumsum(las.astype(jnp.float32), axis=-1)
         return local + carry[..., None] * jnp.exp(prefix)
 
-    return shard_map(body, mesh=ctx.mesh, in_specs=(P(*spec), P(*spec)),
-                     out_specs=P(*spec), check_rep=False)(x, log_a)
+    fn = shard_map(body, mesh=ctx.mesh, in_specs=(P(*spec), P(*spec)),
+                   out_specs=P(*spec), check_vma=False)
+    return Plan("weighted_scan", fn, (x, log_a), ctx, x.ndim - 1, axes)
 
 
-def sharded_ssd(x, dt, a, b, c, *, policy=None, chunk=None,
-                matmul_dtype=None, return_state: bool = False):
-    """Sequence-sharded SSD: per-shard chunked scan + cross-device state
-    carry (the same recurrence one level up: shard finals are chunk finals).
+def sharded_weighted_scan(x, log_a, *, policy=None):
+    """Last-axis decayed scan of a sharded array: per-shard kernel + the
+    1-semiseparable carry combine, propagated through prefix decays."""
+    p = _plan_weighted_scan(x, log_a, policy=policy)
+    return None if p is None else p.run()
 
-    ``x (B, L, H, P)`` sharded on L (dim 1); ``dt (B, L, H)``, ``b``/``c``
-    ``(B, L, G, N)`` must be sharded identically on L; ``a (H,)`` is
-    host-replicated. The returned final state is replicated.
-    """
+
+def _plan_ssd(x, dt, a, b, c, *, policy=None, chunk=None,
+              matmul_dtype=None, return_state: bool = False):
     route = _routing_ctx(x, 1)
     if route is None:
         return None
@@ -200,8 +234,6 @@ def sharded_ssd(x, dt, a, b, c, *, policy=None, chunk=None,
             any(e is not None for e in _full_spec(a.sharding.spec, a.ndim)):
         return None
     dt_spec, b_spec, c_spec = arg_specs
-    if _obs.ACTIVE is not None:
-        _emit_route("ssd", x, 1, ctx, axes)
     from repro.core import dispatch
 
     nd = ctx.axis_sizes[axis]
@@ -221,8 +253,8 @@ def sharded_ssd(x, dt, a, b, c, *, policy=None, chunk=None,
         # y_l += C_l · (prod_{k<=l} exp(lam_k)) h_in
         cdec = jnp.repeat(cs, heads // groups, axis=2).astype(jnp.float32) \
             * jnp.exp(jnp.cumsum(lam, axis=1))[..., None]        # (B,Ll,H,N)
-        y = y + jnp.einsum("blhn,bhpn->blhp", cdec,
-                           h_in).astype(y.dtype)
+        y = y + jnp.einsum("blhn,bhpn->blhp", cdec, h_in,
+                           precision=MXU_PRECISION).astype(y.dtype)
         if not return_state:
             return y
         h_fin = jnp.exp(log_decay)[..., None, None] * h_in + h_last
@@ -232,8 +264,26 @@ def sharded_ssd(x, dt, a, b, c, *, policy=None, chunk=None,
         return y, h_glob
 
     out_specs = P(*spec) if not return_state else (P(*spec), P())
-    out = shard_map(
+    fn = shard_map(
         body, mesh=ctx.mesh,
         in_specs=(P(*spec), P(*dt_spec), P(), P(*b_spec), P(*c_spec)),
-        out_specs=out_specs, check_rep=False)(x, dt, a, b, c)
-    return out
+        out_specs=out_specs, check_vma=False)
+    return Plan("ssd", fn, (x, dt, a, b, c), ctx, 1, axes)
+
+
+def sharded_ssd(x, dt, a, b, c, *, policy=None, chunk=None,
+                matmul_dtype=None, return_state: bool = False):
+    """Sequence-sharded SSD: per-shard chunked scan + cross-device state
+    carry (the same recurrence one level up: shard finals are chunk finals).
+
+    ``x (B, L, H, P)`` sharded on L (dim 1); ``dt (B, L, H)``, ``b``/``c``
+    ``(B, L, G, N)`` must be sharded identically on L; ``a (H,)`` is
+    host-replicated. The returned final state is replicated.
+    """
+    p = _plan_ssd(x, dt, a, b, c, policy=policy, chunk=chunk,
+                  matmul_dtype=matmul_dtype, return_state=return_state)
+    return None if p is None else p.run()
+
+
+_PLANS = {"reduce": _plan_reduce, "scan": _plan_scan,
+          "weighted_scan": _plan_weighted_scan, "ssd": _plan_ssd}

@@ -25,22 +25,24 @@ SRC = Path(__file__).resolve().parents[1] / "src" / "repro"
 def test_compiler_params_resolves_on_this_jax():
     cp = backend.compiler_params(
         dimension_semantics=("parallel", "arbitrary"))
-    assert type(cp) is backend.compiler_params_cls()
+    from jax.experimental.pallas import tpu as pltpu
+
+    assert type(cp) is pltpu.CompilerParams
     assert tuple(cp.dimension_semantics) == ("parallel", "arbitrary")
 
 
-def test_compiler_params_drops_unknown_fields():
-    # a knob from another JAX era must not crash the shim
-    cp = backend.compiler_params(
-        dimension_semantics=("arbitrary",),
-        some_flag_from_the_future=True)
-    assert not hasattr(cp, "some_flag_from_the_future")
+def test_compiler_params_rejects_unknown_fields():
+    # a misspelled knob must fail loudly, not be dropped
+    with pytest.raises(TypeError):
+        backend.compiler_params(dimension_semantics=("arbitrary",),
+                                dimension_semantic=("arbitrary",))
+    with pytest.raises(TypeError):
+        backend.compiler_params(backend="gpu", num_warp=4)
 
 
 def test_no_raw_compiler_params_outside_backend():
-    """Regression guard for the 44-test break: only backend.py may spell
-    out the per-version pltpu compiler-params class."""
-    pat = re.compile(r"pltpu\s*\.\s*(?:TPU)?CompilerParams")
+    """Only backend.py may construct the pltpu compiler-params class."""
+    pat = re.compile(r"pltpu\s*\.\s*CompilerParams")
     offenders = [
         str(p.relative_to(SRC))
         for p in sorted(SRC.rglob("*.py"))
@@ -78,9 +80,8 @@ def test_no_pallas_triton_import_outside_triton_package():
 
 
 def test_no_shard_map_import_outside_parallel_compat():
-    """Only ``parallel/compat.py`` may import ``shard_map`` — the 0.4.x
-    vs 0.6+ rename lives behind exactly one shim (the PR-1 break class:
-    a renamed jax symbol imported from many files)."""
+    """Only ``parallel/compat.py`` may import ``shard_map``: one import
+    site for a jax symbol that has been renamed before."""
     pat = re.compile(
         r"^\s*(?:from\s+jax\.experimental\.shard_map\s+import"
         r"|import\s+jax\.experimental\.shard_map"
@@ -168,6 +169,37 @@ def test_tile_downgrade_warns_once_then_stays_silent(monkeypatch):
                                  interpret_fallback="error")
     with pytest.raises(RuntimeError, match="interpret_fallback"):
         strict.resolve(level="kernel", explicit="tile")
+
+
+def test_unfit_shape_runs_oracle_warns_once_and_records(monkeypatch):
+    """A kernel-path call whose shape the kernel's blocks do not fit runs
+    the fused oracle: it warns once per op, records a kernel_invoke event
+    whose path says oracle, and raises under interpret_fallback='error'."""
+    from repro import obs
+
+    monkeypatch.setattr(kpolicy, "_WARNED", set())
+    x = jnp.ones((4, 100), jnp.float32)         # d=100: not lane-aligned
+    w = jnp.ones((100,), jnp.float32)
+    want = ref.rmsnorm_ref(x, w)
+    with obs.using_obs() as sess:
+        with pytest.warns(UserWarning, match="fused oracle"):
+            got = ops.rmsnorm(x, w, path="interpret")
+        with warnings.catch_warnings():
+            warnings.simplefilter("error")
+            ops.rmsnorm(x, w, path="interpret")  # once per op, not per call
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want))
+    paths = [e["path"] for e in sess.events.events("kernel_invoke")]
+    assert paths == ["oracle", "oracle"]
+    strict = dataclasses.replace(kpolicy.get_policy(),
+                                 interpret_fallback="error")
+    with pytest.raises(RuntimeError, match="interpret_fallback='error'"):
+        ops.rmsnorm(x, w, policy=strict, path="interpret")
+    # a shape the kernel fits runs it, and says so
+    q = jnp.ones((1, 1, 128, 128), jnp.float32)
+    with obs.using_obs() as sess:
+        ops.attention(q, q, q, policy=strict, path="interpret")
+    assert [e["path"] for e in sess.events.events("kernel_invoke")] == \
+        ["interpret"]
 
 
 def test_explicit_tile_backend_labels_are_strict():

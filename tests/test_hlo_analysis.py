@@ -146,9 +146,9 @@ def test_memory_model_slices_not_full_buffers():
 
 
 def _subjaxprs(v):
-    if isinstance(v, jax.core.ClosedJaxpr):
+    if isinstance(v, jax.extend.core.ClosedJaxpr):
         yield v.jaxpr
-    elif isinstance(v, jax.core.Jaxpr):
+    elif isinstance(v, jax.extend.core.Jaxpr):
         yield v
     elif isinstance(v, (tuple, list)):
         for item in v:
@@ -182,8 +182,9 @@ def _scan_structure(path, n):
         if e.primitive.name != "pallas_call":
             continue
         grid = e.params["grid_mapping"].grid
-        cp = e.params.get("compiler_params") or {}
-        sem = (cp.get("mosaic") or {}).get("dimension_semantics") or ()
+        # one pltpu.CompilerParams object, keyed by its backend name
+        (cp,) = e.params["compiler_params"].values()
+        sem = tuple(cp.dimension_semantics)
         semantics.extend(sem)
         for g, s in zip(grid, sem):
             if s == "arbitrary":

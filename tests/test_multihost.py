@@ -263,7 +263,7 @@ def test_two_process_collective_smoke(tmp_path):
             np.full((1,), 1.0 + jax.process_index(), np.float32))
         total = shard_map(lambda v: jax.lax.psum(v, "data"), mesh=mesh,
                           in_specs=P("data"), out_specs=P(None),
-                          check_rep=False)(x)
+                          check_vma=False)(x)
         print("PSUM", float(total.addressable_data(0)[0]))
     """, tmp_path)
     for out in outs:

@@ -434,7 +434,7 @@ def test_obs_scope_writes_artifacts(tmp_path):
     assert "repro_test_total 1" in open(prom).read()
 
 
-def test_time_stats_and_bandwidth_model(monkeypatch):
+def test_time_stats_and_bandwidth_model():
     from benchmarks import common
 
     calls = []
@@ -445,8 +445,17 @@ def test_time_stats_and_bandwidth_model(monkeypatch):
     assert st["p25_s"] <= st["median_s"] <= st["p75_s"]
     assert st["iqr_s"] == pytest.approx(st["p75_s"] - st["p25_s"])
 
-    monkeypatch.setenv(common.ENV_PEAK_GBPS, "100")
+    from repro.launch import peaks
+
+    # the peak comes from the device-kind table: the CPU entry here, the
+    # published v5e numbers for the chip, and an unknown kind raises
+    assert jax.devices()[0].device_kind == "cpu"
     bm = common.bandwidth_model(2_000_000_000, 0.1)
     assert bm["achieved_gbps"] == pytest.approx(20.0)
-    assert bm["peak_gbps"] == 100.0
-    assert bm["pct_peak"] == pytest.approx(20.0)
+    assert bm["peak_gbps"] == peaks.PEAKS["cpu"].hbm_bytes_s / 1e9 == 50.0
+    assert bm["pct_peak"] == pytest.approx(40.0)
+    v5e = peaks.peaks_for("TPU v5 lite")
+    assert (v5e.bf16_flops, v5e.hbm_bytes_s) == (197e12, 819e9)
+    assert "TPU v5e" in v5e.source
+    with pytest.raises(KeyError, match="no published peaks"):
+        peaks.peaks_for("TPU v9 imaginary")
