@@ -609,7 +609,8 @@ class KernelPolicy:
             backend=autotune.current_backend(),
             band=autotune.band(n) if shaped else None,
             level=level, explicit=explicit, chosen_path=str(resolved),
-            tuning=tuning, table_src=table_src)
+            tuning=tuning, table_src=table_src,
+            call=_obs.current_call_id())
         sess.counter(
             "repro_resolutions_total",
             "KernelPolicy.resolve() calls by op/path/level").inc(

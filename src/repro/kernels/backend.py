@@ -284,7 +284,8 @@ def _emit_invoke(name: str, n, dt, p, path: str) -> None:
               n=(int(n) if n is not None else None),
               dtype=(autotune.dtype_tag(dt) if dt is not None else None),
               path=path,
-              tuning=(tuning.as_dict() if tuning is not None else None))
+              tuning=(tuning.as_dict() if tuning is not None else None),
+              call=_obs.current_call_id())
     sess.counter(
         "repro_kernel_invocations_total",
         "kernel-registry executions by op/path").inc(op=name, path=path)

@@ -18,13 +18,14 @@ Submodules:
 * :mod:`repro.obs.runtime` — the active-session machinery
   (``enable``/``disable``/``using_obs``/``active``).
 * :mod:`repro.obs.profiling` — ``jax.profiler`` trace hooks behind
-  ``--profile-dir``.
+  ``--profile-dir``, spans on the trace's clock (``ops/<op>`` and
+  ``shard_ops/<op>`` around every ``repro.ops`` call).
 """
 from repro.obs.events import (DEFAULT_RING, RESOLUTION_FIELDS, EventSink,
                               format_resolution, load_jsonl)
 from repro.obs.metrics import (DEFAULT_BUCKETS, Counter, Gauge, Histogram,
                                MetricsRegistry)
-from repro.obs.profiling import span, tracing
+from repro.obs.profiling import span, spans_enabled, tracing
 from repro.obs.runtime import (ObsSession, active, disable, emit, enable,
                                using_obs)
 
@@ -32,5 +33,6 @@ __all__ = [
     "DEFAULT_BUCKETS", "DEFAULT_RING", "RESOLUTION_FIELDS",
     "Counter", "EventSink", "Gauge", "Histogram", "MetricsRegistry",
     "ObsSession", "active", "disable", "emit", "enable",
-    "format_resolution", "load_jsonl", "span", "tracing", "using_obs",
+    "format_resolution", "load_jsonl", "span", "spans_enabled", "tracing",
+    "using_obs",
 ]

@@ -11,11 +11,15 @@ plus kind-specific fields. The two kinds every tool in the repo agrees on:
     ``backend`` (the jax host backend), ``band`` (log2 bucket),
     ``level`` (``dispatch``/``kernel``), ``explicit`` (the per-call
     ``path=`` label or None), ``chosen_path``, ``tuning`` (knob dict or
-    None) and ``table_src`` (the autotune table file that supplied the
-    bucket, else ``"heuristic"``/``"static"``/``"none"``).
+    None), ``table_src`` (the autotune table file that supplied the
+    bucket, else ``"heuristic"``/``"static"``/``"none"``) and ``call``.
 ``kernel_invoke``
     One kernel-registry execution (``backend.pallas_op``): ``op`` (the
-    registry spelling), ``n``, ``dtype``, ``path``, ``tuning``.
+    registry spelling), ``n``, ``dtype``, ``path``, ``tuning``, ``call``.
+
+``call`` (also on ``sharded_dispatch``) is the sequence number of the
+``repro.ops`` call the event belongs to, the ``call`` stat of its
+``ops/<op>`` span; None outside an observed call.
 
 Everything else (``serving``, ``train_step``, ``ckpt``, ...) is
 free-form but follows the same flat-dict convention so one JSON-lines
@@ -25,6 +29,11 @@ The sink keeps a bounded in-memory ring (newest-wins, so a long serving
 run cannot grow without bound — the fix for the unbounded
 ``ServingEngine.trace`` list) and optionally appends each event to a
 JSON-lines file as it is emitted. Both paths are thread-safe.
+
+``ts`` is wall-clock seconds (``time.time()``). A profiler trace's host
+events count nanoseconds from ``profile_start_time``, a stat of the
+trace's ``Task Environment`` plane in wall-clock nanoseconds, so
+``ts * 1e9 - profile_start_time`` places an event on the trace.
 """
 from __future__ import annotations
 
@@ -37,7 +46,7 @@ from collections import deque
 # schema check and the tests validate against one source of truth.
 RESOLUTION_FIELDS = ("op", "n", "shard_n", "shard_divisor", "dtype",
                     "backend", "band", "level", "explicit", "chosen_path",
-                    "tuning", "table_src")
+                    "tuning", "table_src", "call")
 
 DEFAULT_RING = 4096
 

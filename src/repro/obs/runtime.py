@@ -14,6 +14,12 @@ background writer, jit tracing), and a per-context session would silently
 lose exactly those records. ``using_obs`` still nests — it saves and
 restores the previous session — it just isn't thread-local.
 
+While a session is active it also holds one ``jax.monitoring`` listener,
+which counts JAX's trace, lowering, backend-compile and persistent-cache
+events by the observed call that caused them (:func:`begin_call` marks
+the innermost call on each thread; ``repro.ops`` marks each op call);
+nothing is registered with JAX while observability is off.
+
 Typical use::
 
     from repro import obs
@@ -27,6 +33,8 @@ Typical use::
 from __future__ import annotations
 
 import contextlib
+import dataclasses
+import itertools
 import threading
 from typing import Iterator
 
@@ -38,6 +46,17 @@ from repro.obs.metrics import MetricsRegistry
 ACTIVE: "ObsSession | None" = None
 
 _LOCK = threading.Lock()
+
+# JAX monitoring events -> the ``stage`` label of the compile counters.
+# JAX records backend_compile_duration around a persistent-cache fetch as
+# well, so ``compile`` covers compiling or fetching and ``cache_fetch`` is
+# the part of it spent reading the cache.
+COMPILE_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_fetch",
+}
 
 
 class ObsSession:
@@ -86,23 +105,36 @@ def active() -> ObsSession | None:
     return ACTIVE
 
 
+def _swap(sess: ObsSession | None) -> ObsSession | None:
+    """Make ``sess`` the active session and return the previous one; the
+    compile listener is registered exactly while some session is active."""
+    global ACTIVE
+    import jax
+
+    with _LOCK:
+        prev, ACTIVE = ACTIVE, sess
+        if prev is None and sess is not None:
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_compile_event)
+        elif prev is not None and sess is None:
+            jax.monitoring.unregister_event_duration_listener(
+                _on_compile_event)
+    return prev
+
+
 def enable(session: ObsSession | None = None, **kw) -> ObsSession:
     """Install ``session`` (or a fresh one built from ``kw``) as the
     active session and return it. Prefer the scoped :func:`using_obs`
     unless the session should outlive the frame."""
-    global ACTIVE
     sess = session if session is not None else ObsSession(**kw)
-    with _LOCK:
-        ACTIVE = sess
+    _swap(sess)
     return sess
 
 
 def disable() -> None:
     """Deactivate observability (the active session, if any, is left
     intact for post-hoc reads — only emission stops)."""
-    global ACTIVE
-    with _LOCK:
-        ACTIVE = None
+    _swap(None)
 
 
 @contextlib.contextmanager
@@ -111,15 +143,12 @@ def using_obs(session: ObsSession | None = None,
     """Scoped observability: activate a session, restore the previous one
     (usually None) on exit. The session's JSON-lines file, if any, is
     closed on exit; its in-memory metrics/events stay readable."""
-    global ACTIVE
     sess = session if session is not None else ObsSession(**kw)
-    with _LOCK:
-        prev, ACTIVE = ACTIVE, sess
+    prev = _swap(sess)
     try:
         yield sess
     finally:
-        with _LOCK:
-            ACTIVE = prev
+        _swap(prev)
         if session is None:       # we own the sink: release the file
             sess.close()
 
@@ -128,3 +157,58 @@ def emit(kind: str, **fields) -> dict | None:
     """Emit one event into the active session (no-op when disabled)."""
     sess = ACTIVE
     return None if sess is None else sess.emit(kind, **fields)
+
+
+# ---------------------------------------------------------------------------
+# Observed calls: one identifier per call, shared by its spans and events
+
+_CALL_IDS = itertools.count(1)
+_LOCAL = threading.local()        # .call: innermost observed Call here
+
+
+@dataclasses.dataclass
+class Call:
+    """One observed call: ``name`` labels the compile events it causes,
+    ``id`` is its per-process sequence number, ``outer`` the call it runs
+    inside on this thread."""
+    name: str
+    id: int = dataclasses.field(default_factory=lambda: next(_CALL_IDS))
+    outer: "Call | None" = None
+
+
+def begin_call(call: Call) -> None:
+    """Make ``call`` the innermost observed call on this thread."""
+    call.outer = getattr(_LOCAL, "call", None)
+    _LOCAL.call = call
+
+
+def end_call(call: Call) -> None:
+    """Restore the call that ``call`` ran inside."""
+    _LOCAL.call = call.outer
+
+
+def current_call() -> Call | None:
+    """The innermost observed call on this thread."""
+    return getattr(_LOCAL, "call", None)
+
+
+def current_call_id() -> int | None:
+    c = getattr(_LOCAL, "call", None)
+    return None if c is None else c.id
+
+
+def _on_compile_event(event: str, duration: float, **_) -> None:
+    """The session's ``jax.monitoring`` listener: JAX's compile events,
+    by the innermost observed call on this thread (else ``other``)."""
+    stage = COMPILE_STAGES.get(event)
+    sess = ACTIVE
+    if stage is None or sess is None:
+        return
+    c = getattr(_LOCAL, "call", None)
+    op = "other" if c is None else c.name
+    sess.counter("repro_compile_events_total",
+                 "JAX traces, lowerings, backend compiles and persistent-"
+                 "cache fetches by repro.ops op").inc(op=op, stage=stage)
+    sess.counter("repro_compile_seconds_total",
+                 "seconds of those events by repro.ops op").inc(
+        duration, op=op, stage=stage)

@@ -35,8 +35,21 @@ inside ``shard_map``; it takes an axis name instead of a policy.
 The exported surface is exactly ``__all__``; a CI test pins it. The
 ``path=`` kwarg is a deprecated alias for a bare-label policy and warns
 once per process.
+
+Every op is observed (``repro.obs``): while spans are on it runs inside an
+``ops/<op>`` span with stats ``call`` (a per-process sequence number that
+its ``shard_ops/<op>`` span, compile counts and events share), ``n`` (the
+bucket-axis length) and ``routed`` (whether ``shard_ops`` took it); while
+a session is active it counts ``repro_ops_calls_total{op, routed}`` and
+attributes JAX's compile events to ``op``. Under ``jax.jit`` all of this
+happens at trace time only. With observability off the wrapper costs two
+module-global loads.
 """
 from __future__ import annotations
+
+import dataclasses
+import functools
+import inspect
 
 import jax
 
@@ -52,6 +65,8 @@ from repro.core.policy import (  # noqa: F401  (re-exported API)
     using_policy,
 )
 from repro.kernels import ops as _kops
+from repro.obs import profiling as _prof
+from repro.obs import runtime as _obs
 
 __all__ = [
     "KernelPolicy",
@@ -69,6 +84,65 @@ __all__ = [
     "using_policy",
     "weighted_scan",
 ]
+
+
+@dataclasses.dataclass
+class _OpCall(_obs.Call):
+    """An observed op call; ``routed`` turns true when ``shard_ops`` took
+    it (:func:`_routed`)."""
+    routed: bool = False
+
+
+def _observed(axis: int):
+    """Observe a public op whose first argument's ``axis`` is the bucket
+    axis (see the module docstring)."""
+    def wrap(fn):
+        op = fn.__name__
+        first = next(iter(inspect.signature(fn).parameters))
+
+        @functools.wraps(fn)
+        def observed(*args, **kwargs):
+            if not _prof._TRACING and _obs.ACTIVE is None:
+                return fn(*args, **kwargs)
+            x = args[0] if args else kwargs[first]
+            return _observe(op, x.shape[axis], fn, args, kwargs)
+        return observed
+    return wrap
+
+
+def _observe(op: str, n: int, fn, args, kwargs):
+    """Run ``fn`` as an observed call of ``op``: in an ``ops/<op>`` span
+    (stats ``call``, ``n``, ``routed``) when spans are on, marked as the
+    current call for the compile counter and the call's events, and
+    counted in ``repro_ops_calls_total{op, routed}`` when a session is
+    active."""
+    c = _OpCall(op)
+    _obs.begin_call(c)
+    try:
+        if _prof._TRACING:
+            ann = jax.profiler.TraceAnnotation(f"ops/{op}", call=c.id,
+                                               n=int(n))
+            with ann:
+                out = fn(*args, **kwargs)
+                ann.set_metadata(routed=c.routed)
+        else:
+            out = fn(*args, **kwargs)
+    finally:
+        _obs.end_call(c)
+    sess = _obs.ACTIVE
+    if sess is not None:
+        sess.counter("repro_ops_calls_total",
+                     "repro.ops calls by op, and whether shard_ops routed "
+                     "them").inc(op=op, routed=str(c.routed).lower())
+    return out
+
+
+def _routed(out):
+    """Mark the current observed op call as taken by ``shard_ops``."""
+    c = _obs.current_call()
+    if c is not None:
+        c.routed = True
+    return out
 
 
 def _policy_arg(policy, path):
@@ -98,6 +172,7 @@ def _shard_ops():
     return shard_ops
 
 
+@_observed(-1)
 def reduce(x: jax.Array, *, policy=None, path: str | None = None
            ) -> jax.Array:
     """Segmented sum over the last axis of ``x (..., n)`` -> f32
@@ -105,10 +180,11 @@ def reduce(x: jax.Array, *, policy=None, path: str | None = None
     policy = _policy_arg(policy, path)
     out = _shard_ops().sharded_reduce(x, policy=policy)
     if out is not None:
-        return out
+        return _routed(out)
     return _dispatch.reduce(x, policy=policy)
 
 
+@_observed(-1)
 def scan(x: jax.Array, *, policy=None, exclusive: bool = False,
          path: str | None = None) -> jax.Array:
     """Prefix sum over the last axis -> f32, same shape
@@ -116,20 +192,22 @@ def scan(x: jax.Array, *, policy=None, exclusive: bool = False,
     policy = _policy_arg(policy, path)
     out = _shard_ops().sharded_scan(x, policy=policy, exclusive=exclusive)
     if out is not None:
-        return out
+        return _routed(out)
     return _dispatch.scan(x, policy=policy, exclusive=exclusive)
 
 
+@_observed(-1)
 def weighted_scan(x: jax.Array, log_a: jax.Array, *, policy=None,
                   path: str | None = None) -> jax.Array:
     """Decayed scan ``y_i = exp(log_a_i) * y_{i-1} + x_i`` -> f32."""
     policy = _policy_arg(policy, path)
     out = _shard_ops().sharded_weighted_scan(x, log_a, policy=policy)
     if out is not None:
-        return out
+        return _routed(out)
     return _dispatch.weighted_scan(x, log_a, policy=policy)
 
 
+@_observed(-1)
 def ragged_reduce(x: jax.Array, seg_ids: jax.Array, n_segments: int, *,
                   policy=None, path: str | None = None) -> jax.Array:
     """Bucketed segmented sum: ``x (..., n)`` + ``seg_ids`` -> f32
@@ -138,6 +216,7 @@ def ragged_reduce(x: jax.Array, seg_ids: jax.Array, n_segments: int, *,
                                    policy=_policy_arg(policy, path))
 
 
+@_observed(-1)
 def ragged_scan(x: jax.Array, seg_ids: jax.Array, n_segments: int, *,
                 policy=None, debug: bool = False,
                 path: str | None = None) -> jax.Array:
@@ -148,6 +227,7 @@ def ragged_scan(x: jax.Array, seg_ids: jax.Array, n_segments: int, *,
                                  debug=debug)
 
 
+@_observed(-1)
 def rmsnorm(x: jax.Array, w: jax.Array, *, eps: float = 1e-6,
             policy=None, path: str | None = None) -> jax.Array:
     """RMSNorm over the last axis (differentiable; MXU Σx² on the kernel
@@ -155,6 +235,7 @@ def rmsnorm(x: jax.Array, w: jax.Array, *, eps: float = 1e-6,
     return _kops.rmsnorm(x, w, eps=eps, policy=_policy_arg(policy, path))
 
 
+@_observed(1)
 def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
               causal: bool = True, window: int | None = None,
               scale: float | None = None, policy=None,
@@ -166,6 +247,7 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                                policy=_policy_arg(policy, path))
 
 
+@_observed(1)
 def ssd(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
         c: jax.Array, *, policy=None, chunk: int | None = None,
         matmul_dtype=None, return_state: bool = False,
@@ -177,7 +259,7 @@ def ssd(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
                                    chunk=chunk, matmul_dtype=matmul_dtype,
                                    return_state=return_state)
     if out is not None:
-        return out
+        return _routed(out)
     return _dispatch.ssd(x, dt, a, b, c, policy=policy, chunk=chunk,
                          matmul_dtype=matmul_dtype,
                          return_state=return_state)

@@ -39,6 +39,7 @@ from repro.core.distributed import (
     weighted_exclusive_carry,
 )
 from repro.kernels.layout import MXU_PRECISION
+from repro.obs import profiling as _prof
 from repro.obs import runtime as _obs
 from repro.parallel.compat import shard_map
 from repro.parallel.mesh_context import (
@@ -62,10 +63,18 @@ class Plan:
     axes: tuple
 
     def run(self):
+        """Run the shard_map program eagerly (JAX traces, lowers and
+        compiles its body here), inside a ``shard_ops/<op>`` span that
+        shares the observed call's ``call`` stat when spans are on."""
         if _obs.ACTIVE is not None:
             _emit_route(self.op, self.args[0], self.dim, self.ctx,
                         self.axes)
-        return self.fn(*self.args)
+        if not _prof._TRACING:
+            return self.fn(*self.args)
+        call = _obs.current_call_id()
+        stats = {} if call is None else {"call": call}
+        with _prof.span(f"shard_ops/{self.op}", **stats):
+            return self.fn(*self.args)
 
 
 def plan(op: str, *args, **kwargs) -> "Plan | None":
@@ -87,7 +96,8 @@ def _emit_route(op: str, x, dim: int, ctx, axes) -> None:
     for a in axes:
         nshards *= sizes.get(a, 1)
     sess.emit("sharded_dispatch", op=op, n=int(x.shape[dim]),
-              dim=int(dim), mesh_axes=list(axes), nshards=int(nshards))
+              dim=int(dim), mesh_axes=list(axes), nshards=int(nshards),
+              call=_obs.current_call_id())
     sess.counter(
         "repro_sharded_dispatch_total",
         "calls routed through shard_map by op").inc(op=op)

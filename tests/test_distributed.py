@@ -195,6 +195,54 @@ def test_shard_ops_route_and_match_unsharded():
     assert "SHARD_OPS_OK" in out
 
 
+def test_shard_ops_spans_and_compile_counter(tmp_path):
+    """A routed eager scan: its ``ops/scan`` span holds a
+    ``shard_ops/scan`` span with the same ``call``, the
+    ``sharded_dispatch`` event carries that call, and the compile counter
+    puts the lowerings of the per-call shard_map program under
+    ``op="scan"``."""
+    out = _run(4, f"""
+        import glob
+        from jax.profiler import ProfileData
+        from jax.sharding import NamedSharding
+        from repro import obs, ops
+        from repro.obs import profiling
+        from repro.parallel.mesh_context import make_context
+
+        ctx = make_context("data=4")
+        xs = jax.device_put(jax.random.normal(jax.random.PRNGKey(0),
+                                              (8, 1024)),
+                            NamedSharding(ctx.mesh, P(None, "data")))
+        with ctx, obs.using_obs() as sess, \\
+                profiling.tracing({str(tmp_path)!r}):
+            jax.block_until_ready(ops.scan(xs, policy="fused"))
+        (pb,) = glob.glob({str(tmp_path)!r} + "/**/*.xplane.pb",
+                          recursive=True)
+        spans = {{}}
+        for plane in ProfileData.from_file(pb).planes:
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name in ("ops/scan", "shard_ops/scan"):
+                        spans[e.name] = (e.start_ns, e.end_ns,
+                                         dict(e.stats))
+        outer, inner = spans["ops/scan"], spans["shard_ops/scan"]
+        assert outer[2]["routed"] == 1 and outer[2]["n"] == 1024
+        assert inner[2]["call"] == outer[2]["call"]
+        assert outer[0] <= inner[0] <= inner[1] <= outer[1]
+        (route,) = sess.events.events("sharded_dispatch")
+        assert route["call"] == outer[2]["call"]
+        m = sess.metrics
+        assert m.get("repro_compile_events_total").value(
+            op="scan", stage="lower") > 0
+        assert m.get("repro_compile_seconds_total").value(
+            op="scan", stage="compile") > 0
+        assert m.get("repro_ops_calls_total").value(
+            op="scan", routed="true") == 1
+        print("SHARD_SPANS_OK")
+    """)
+    assert "SHARD_SPANS_OK" in out
+
+
 def test_shard_ops_ssd_matches_unsharded():
     """Sequence-sharded SSD (shard finals carried by the 1-semiseparable
     combine) against the unsharded op, y and final state both."""

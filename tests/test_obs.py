@@ -22,13 +22,41 @@ from repro.obs.metrics import MetricsRegistry
 # off by default
 
 
-def test_disabled_by_default():
+def _compile_listener_registered() -> bool:
+    from jax._src import monitoring   # the public module cannot list them
+
+    return obs_runtime._on_compile_event in \
+        monitoring.get_event_duration_listeners()
+
+
+def test_disabled_by_default(tmp_path):
+    import repro.ops as rops
+
     assert obs.active() is None
+    assert not _compile_listener_registered()
     pol = KernelPolicy(interpret_fallback="silent")
     pol.resolve(op="reduce", n=1024, dtype=jnp.float32)  # must not record
     with obs.using_obs() as sess:
         assert sess.events.emitted == 0          # nothing retroactive
+        assert _compile_listener_registered()    # one, while active
     assert obs.active() is None                  # scope restored
+    assert not _compile_listener_registered()
+    obs.enable()
+    assert _compile_listener_registered()
+    obs.disable()
+    assert not _compile_listener_registered()
+    # a profiler trace alone, with the repo's spans off: repro.ops records
+    # no span and numbers no call
+    ids = repr(obs_runtime._CALL_IDS)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        jax.block_until_ready(rops.scan(jnp.ones((2, 64)), policy="fused"))
+    finally:
+        jax.profiler.stop_trace()
+    assert repr(obs_runtime._CALL_IDS) == ids
+    assert obs_runtime.current_call() is None
+    assert not [name for name, *_ in _host_events(tmp_path)
+                if name.startswith(("ops/", "shard_ops/"))]
 
 
 def test_resolve_emits_only_inside_scope():
@@ -243,6 +271,94 @@ def test_resolution_counter_increments():
         c = sess.metrics.get("repro_resolutions_total")
         assert c is not None
         assert sum(c.series().values()) == 1
+
+
+# ---------------------------------------------------------------------------
+# repro.ops spans and the compile counter
+
+
+def _host_events(trace_dir):
+    """``(name, start_ns, end_ns, stats, profile_start_time)`` of every
+    host event named like a span (``layer/name``) in the one trace under
+    ``trace_dir``."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (pb,) = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
+    data = ProfileData.from_file(pb)
+    start = None
+    for plane in data.planes:
+        if plane.name == "Task Environment":
+            start = dict(plane.stats)["profile_start_time"]
+    return [(e.name, e.start_ns, e.end_ns, dict(e.stats), start)
+            for plane in data.planes if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events if "/" in e.name]
+
+
+def test_event_in_an_ops_span_lands_inside_it_on_the_trace_clock(tmp_path):
+    """Events keep wall-clock ``ts``; minus the trace's
+    ``profile_start_time`` it falls inside the ``ops/<op>`` span of the
+    call the event carries."""
+    import repro.ops as rops
+    from repro.obs import profiling
+
+    x = jnp.arange(256, dtype=jnp.float32).reshape(4, 64)
+    with obs.using_obs() as sess, profiling.tracing(str(tmp_path)):
+        jax.block_until_ready(rops.reduce(x, policy="interpret"))
+    res = sess.events.events("resolution")[-1]
+    inv = sess.events.events("kernel_invoke")[-1]
+    assert res["call"] is not None and inv["call"] == res["call"]
+    spans = [ev for ev in _host_events(tmp_path) if ev[0] == "ops/reduce"]
+    assert len(spans) == 1
+    _, s, e, stats, start = spans[0]
+    assert stats["call"] == res["call"] and stats["n"] == 64
+    assert stats["routed"] == 0
+    for ev in (res, inv):
+        at = ev["ts"] * 1e9 - start
+        assert s <= at <= e, (s, at, e)
+    assert sess.metrics.get("repro_ops_calls_total").value(
+        op="reduce", routed="false") == 1
+
+
+def test_spans_enabled_is_scoped_and_tracing_takes_profile_options(
+        tmp_path):
+    from repro.obs import profiling
+
+    assert not profiling._TRACING
+    with profiling.spans_enabled():
+        assert profiling._TRACING
+        with profiling.spans_enabled():
+            assert profiling._TRACING
+        assert profiling._TRACING
+    assert not profiling._TRACING
+    with profiling.tracing(str(tmp_path)):
+        with profiling.span("test/section", step=3):
+            jax.block_until_ready(jnp.ones(4) + 1)
+    assert not profiling._TRACING
+    (ev,) = [e for e in _host_events(tmp_path) if e[0] == "test/section"]
+    assert ev[3] == {"step": 3}
+
+
+def test_compile_counter_counts_a_jitted_call_once():
+    """Compiles are counted by stage; a jitted call's second run adds
+    none, and its repro.ops call is counted at trace time only."""
+    import repro.ops as rops
+
+    f = jax.jit(lambda x: rops.scan(x, policy="fused") * 2.0)
+    x = jnp.ones((3, 32), jnp.float32)
+    with obs.using_obs() as sess:
+        jax.block_until_ready(f(x))
+        first = sess.metrics.snapshot()
+        jax.block_until_ready(f(x))
+        again = sess.metrics.snapshot()
+    events = first["repro_compile_events_total"]["series"]
+    stages = {s["labels"]["stage"] for s in events}
+    assert {"trace", "lower", "compile"} <= stages
+    assert again == first
+    calls = first["repro_ops_calls_total"]["series"]
+    assert calls == [{"labels": {"op": "scan", "routed": "false"},
+                      "value": 1}]
 
 
 # ---------------------------------------------------------------------------
