@@ -107,10 +107,14 @@ def active() -> ObsSession | None:
 
 def _swap(sess: ObsSession | None) -> ObsSession | None:
     """Make ``sess`` the active session and return the previous one; the
-    compile listener is registered exactly while some session is active."""
+    compile listener is registered exactly while some session is active,
+    and the session holds its compile counters from the start, so a scope
+    that compiles nothing reads 0."""
     global ACTIVE
     import jax
 
+    if sess is not None:
+        _compile_counters(sess)
     with _LOCK:
         prev, ACTIVE = ACTIVE, sess
         if prev is None and sess is not None:
@@ -197,6 +201,16 @@ def current_call_id() -> int | None:
     return None if c is None else c.id
 
 
+def _compile_counters(sess: ObsSession):
+    """The session's ``repro_compile_events_total`` and
+    ``repro_compile_seconds_total`` counters."""
+    return (sess.counter("repro_compile_events_total",
+                         "JAX traces, lowerings, backend compiles and "
+                         "persistent-cache fetches by repro.ops op"),
+            sess.counter("repro_compile_seconds_total",
+                         "seconds of those events by repro.ops op"))
+
+
 def _on_compile_event(event: str, duration: float, **_) -> None:
     """The session's ``jax.monitoring`` listener: JAX's compile events,
     by the innermost observed call on this thread (else ``other``)."""
@@ -206,9 +220,6 @@ def _on_compile_event(event: str, duration: float, **_) -> None:
         return
     c = getattr(_LOCAL, "call", None)
     op = "other" if c is None else c.name
-    sess.counter("repro_compile_events_total",
-                 "JAX traces, lowerings, backend compiles and persistent-"
-                 "cache fetches by repro.ops op").inc(op=op, stage=stage)
-    sess.counter("repro_compile_seconds_total",
-                 "seconds of those events by repro.ops op").inc(
-        duration, op=op, stage=stage)
+    events, seconds = _compile_counters(sess)
+    events.inc(op=op, stage=stage)
+    seconds.inc(duration, op=op, stage=stage)

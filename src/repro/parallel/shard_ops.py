@@ -23,6 +23,12 @@ a trace — inside jit, GSPMD already partitions the fused forms), the
 array's sharding is a ``NamedSharding`` over the context's mesh, the
 bucket axis is actually sharded, and the shard is even. ``repro.ops``
 consults them; ``core.dispatch`` itself stays mesh-free.
+
+Each routed program is built once: :func:`_program` keeps one
+``jax.jit(shard_map(...))`` per key of everything its trace reads (the
+op, the mesh, every argument's full spec, shape and dtype, the bucket
+axes, the resolved policy and SSD's static arguments), so a later call
+with the same key dispatches the compiled program and traces nothing.
 """
 from __future__ import annotations
 
@@ -38,6 +44,7 @@ from repro.core.distributed import (
     dist_exclusive_carry,
     weighted_exclusive_carry,
 )
+from repro.core.policy import as_policy
 from repro.kernels.layout import MXU_PRECISION
 from repro.obs import profiling as _prof
 from repro.obs import runtime as _obs
@@ -53,22 +60,23 @@ __all__ = ["plan", "sharded_reduce", "sharded_scan",
 
 @dataclasses.dataclass(frozen=True)
 class Plan:
-    """One routed call: ``fn(*args)`` is the shard_map program it runs
-    (``jax.jit(fn).lower(*args)`` shows its collectives)."""
+    """One routed call: ``fn(*args)`` is the jitted shard_map program it
+    runs (``jax.jit(fn).lower(*args)`` shows its collectives); ``cached``
+    says whether an earlier call had built it."""
     op: str
     fn: Callable
     args: tuple
     ctx: object
     dim: int
     axes: tuple
+    cached: bool
 
     def run(self):
-        """Run the shard_map program eagerly (JAX traces, lowers and
-        compiles its body here), inside a ``shard_ops/<op>`` span that
-        shares the observed call's ``call`` stat when spans are on."""
+        """Run the program (JAX traces and compiles it on its first call
+        only), inside a ``shard_ops/<op>`` span that shares the observed
+        call's ``call`` stat when spans are on."""
         if _obs.ACTIVE is not None:
-            _emit_route(self.op, self.args[0], self.dim, self.ctx,
-                        self.axes)
+            _emit_route(self)
         if not _prof._TRACING:
             return self.fn(*self.args)
         call = _obs.current_call_id()
@@ -84,23 +92,49 @@ def plan(op: str, *args, **kwargs) -> "Plan | None":
     return _PLANS[op](*args, **kwargs)
 
 
-def _emit_route(op: str, x, dim: int, ctx, axes) -> None:
+def _emit_route(p: Plan) -> None:
     """One ``sharded_dispatch`` event when a shard_map route is taken
     (only called when an obs session is active) — the audit record that a
-    call left plain dispatch for the mesh path, and over which axes."""
+    call left plain dispatch for the mesh path, and over which axes — and
+    whether the call found its program built."""
     sess = _obs.ACTIVE
     if sess is None:
         return
-    sizes = ctx.axis_sizes
+    sizes = p.ctx.axis_sizes
     nshards = 1
-    for a in axes:
+    for a in p.axes:
         nshards *= sizes.get(a, 1)
-    sess.emit("sharded_dispatch", op=op, n=int(x.shape[dim]),
-              dim=int(dim), mesh_axes=list(axes), nshards=int(nshards),
+    sess.emit("sharded_dispatch", op=p.op, n=int(p.args[0].shape[p.dim]),
+              dim=int(p.dim), mesh_axes=list(p.axes), nshards=int(nshards),
               call=_obs.current_call_id())
     sess.counter(
         "repro_sharded_dispatch_total",
-        "calls routed through shard_map by op").inc(op=op)
+        "calls routed through shard_map by op").inc(op=p.op)
+    sess.counter(
+        "repro_sharded_program_cache_total",
+        "routed calls by op, and whether their program was already built"
+    ).inc(op=p.op, outcome="hit" if p.cached else "miss")
+
+
+# The routed programs by key (see :func:`_program`): one per key a process
+# routes, kept for its life, as ``jax.jit`` keeps one executable per shape.
+_PROGRAMS: dict = {}
+
+
+def _program(key: tuple, build: Callable) -> tuple[Callable, bool]:
+    """The jitted program for ``key``, built from ``build()`` (a
+    ``shard_map``) on the key's first call, and whether it was cached.
+    The key holds everything the body's trace reads."""
+    fn = _PROGRAMS.get(key)
+    if fn is not None:
+        return fn, True
+    fn = _PROGRAMS[key] = jax.jit(build())
+    return fn, False
+
+
+def _avals(*args) -> tuple:
+    """The shape and dtype of each argument, for a program's key."""
+    return tuple((tuple(a.shape), jnp.dtype(a.dtype)) for a in args)
 
 
 def _routing_ctx(x, dim: int):
@@ -138,16 +172,20 @@ def _plan_reduce(x, *, policy=None):
     if route is None:
         return None
     ctx, spec, axes = route
+    pol = as_policy(policy)
     from repro.core import dispatch
 
-    def body(xs):
+    def shard_reduce(xs):
         with shard_local_scope():
-            part = dispatch.reduce(xs, policy=policy)
+            part = dispatch.reduce(xs, policy=pol)
         return jax.lax.psum(part, axes)
 
-    fn = shard_map(body, mesh=ctx.mesh, in_specs=(P(*spec),),
-                   out_specs=P(*spec[:-1]), check_vma=False)
-    return Plan("reduce", fn, (x,), ctx, x.ndim - 1, axes)
+    fn, cached = _program(
+        ("reduce", ctx.mesh, spec, axes, _avals(x), pol),
+        lambda: shard_map(shard_reduce, mesh=ctx.mesh,
+                          in_specs=(P(*spec),), out_specs=P(*spec[:-1]),
+                          check_vma=False))
+    return Plan("reduce", fn, (x,), ctx, x.ndim - 1, axes, cached)
 
 
 def sharded_reduce(x, *, policy=None):
@@ -166,17 +204,21 @@ def _plan_scan(x, *, policy=None, exclusive: bool = False):
     ctx, spec, axes = route
     if len(axes) != 1:
         return None  # multi-axis bucket sharding: fall back
+    pol = as_policy(policy)
     from repro.core import dispatch
 
-    def body(xs):
+    def shard_scan(xs):
         with shard_local_scope():
-            local = dispatch.scan(xs, policy=policy)
+            local = dispatch.scan(xs, policy=pol)
         carry = dist_exclusive_carry(local[..., -1], axes[0])
         return local + carry[..., None]
 
-    fn = shard_map(body, mesh=ctx.mesh, in_specs=(P(*spec),),
-                   out_specs=P(*spec), check_vma=False)
-    return Plan("scan", fn, (x,), ctx, x.ndim - 1, axes)
+    fn, cached = _program(
+        ("scan", ctx.mesh, spec, axes, _avals(x), pol),
+        lambda: shard_map(shard_scan, mesh=ctx.mesh,
+                          in_specs=(P(*spec),), out_specs=P(*spec),
+                          check_vma=False))
+    return Plan("scan", fn, (x,), ctx, x.ndim - 1, axes, cached)
 
 
 def sharded_scan(x, *, policy=None, exclusive: bool = False):
@@ -198,19 +240,24 @@ def _plan_weighted_scan(x, log_a, *, policy=None):
     if not isinstance(la_sh, NamedSharding) \
             or _full_spec(la_sh.spec, log_a.ndim) != spec:
         return None
+    pol = as_policy(policy)
     from repro.core import dispatch
 
-    def body(xs, las):
+    def shard_weighted_scan(xs, las):
         with shard_local_scope():
-            local = dispatch.weighted_scan(xs, las, policy=policy)
+            local = dispatch.weighted_scan(xs, las, policy=pol)
         log_decay = jnp.sum(las.astype(jnp.float32), axis=-1)
         carry = weighted_exclusive_carry(local[..., -1], log_decay, axes[0])
         prefix = jnp.cumsum(las.astype(jnp.float32), axis=-1)
         return local + carry[..., None] * jnp.exp(prefix)
 
-    fn = shard_map(body, mesh=ctx.mesh, in_specs=(P(*spec), P(*spec)),
-                   out_specs=P(*spec), check_vma=False)
-    return Plan("weighted_scan", fn, (x, log_a), ctx, x.ndim - 1, axes)
+    fn, cached = _program(
+        ("weighted_scan", ctx.mesh, spec, axes, _avals(x, log_a), pol),
+        lambda: shard_map(shard_weighted_scan, mesh=ctx.mesh,
+                          in_specs=(P(*spec), P(*spec)),
+                          out_specs=P(*spec), check_vma=False))
+    return Plan("weighted_scan", fn, (x, log_a), ctx, x.ndim - 1, axes,
+                cached)
 
 
 def sharded_weighted_scan(x, log_a, *, policy=None):
@@ -244,16 +291,17 @@ def _plan_ssd(x, dt, a, b, c, *, policy=None, chunk=None,
             any(e is not None for e in _full_spec(a.sharding.spec, a.ndim)):
         return None
     dt_spec, b_spec, c_spec = arg_specs
+    pol = as_policy(policy)
     from repro.core import dispatch
 
     nd = ctx.axis_sizes[axis]
     heads = x.shape[2]
     groups = b.shape[2]
 
-    def body(xs, dts, a_r, bs, cs):
+    def shard_ssd(xs, dts, a_r, bs, cs):
         with shard_local_scope():
             y, h_last = dispatch.ssd(
-                xs, dts, a_r, bs, cs, policy=policy, chunk=chunk,
+                xs, dts, a_r, bs, cs, policy=pol, chunk=chunk,
                 matmul_dtype=matmul_dtype, return_state=True)
         # shard-level recurrence: H_i = exp(L_i) H_{i-1} + h_last_i
         lam = dts.astype(jnp.float32) * a_r.astype(jnp.float32)  # (B, Ll, H)
@@ -274,11 +322,15 @@ def _plan_ssd(x, dt, a, b, c, *, policy=None, chunk=None,
         return y, h_glob
 
     out_specs = P(*spec) if not return_state else (P(*spec), P())
-    fn = shard_map(
-        body, mesh=ctx.mesh,
-        in_specs=(P(*spec), P(*dt_spec), P(), P(*b_spec), P(*c_spec)),
-        out_specs=out_specs, check_vma=False)
-    return Plan("ssd", fn, (x, dt, a, b, c), ctx, 1, axes)
+    fn, cached = _program(
+        ("ssd", ctx.mesh, spec, dt_spec, b_spec, c_spec, axes,
+         _avals(x, dt, a, b, c), pol, chunk, matmul_dtype,
+         bool(return_state)),
+        lambda: shard_map(
+            shard_ssd, mesh=ctx.mesh,
+            in_specs=(P(*spec), P(*dt_spec), P(), P(*b_spec), P(*c_spec)),
+            out_specs=out_specs, check_vma=False))
+    return Plan("ssd", fn, (x, dt, a, b, c), ctx, 1, axes, cached)
 
 
 def sharded_ssd(x, dt, a, b, c, *, policy=None, chunk=None,

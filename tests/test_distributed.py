@@ -281,6 +281,99 @@ def test_shard_ops_ssd_matches_unsharded():
     assert "SHARD_SSD_OK" in out
 
 
+@pytest.mark.parametrize("op", ["reduce", "scan", "weighted_scan", "ssd"])
+def test_shard_ops_builds_each_routed_program_once(op):
+    """Two routed calls of one op on two inputs of the same shape: both
+    match the unsharded op, the second lowers nothing, and the program
+    cache reads one miss and one hit."""
+    out = _run(4, f"""
+        from jax.sharding import NamedSharding
+        from repro import obs, ops
+        from repro.parallel.mesh_context import make_context
+
+        ctx = make_context("data=4")
+        seq = lambda nd: NamedSharding(
+            ctx.mesh, P(*((None, "data") + (None,) * (nd - 2))))
+
+        def inputs(seed):
+            ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+            if {op!r} == "ssd":
+                bsz, L, h, p, g, n = 1, 128, 2, 8, 1, 4
+                return (0.2 * jax.random.normal(ks[0], (bsz, L, h, p)),
+                        jax.nn.softplus(jax.random.normal(ks[1],
+                                                          (bsz, L, h))),
+                        -jnp.exp(0.2 * jax.random.normal(ks[2], (h,))),
+                        jax.random.normal(ks[3], (bsz, L, g, n)) / 2,
+                        jax.random.normal(ks[4], (bsz, L, g, n)) / 2)
+            x = jax.random.normal(ks[0], (3, 1024))
+            if {op!r} == "weighted_scan":
+                return x, -jax.random.uniform(ks[1], (3, 1024))
+            return (x,)
+
+        def shard(args):
+            return [a if a.ndim == 1 else jax.device_put(a, seq(a.ndim))
+                    for a in args]
+
+        call = getattr(ops, {op!r})
+        tol = dict(reduce=1e-4, scan=1e-2, weighted_scan=1e-3, ssd=2e-3)
+        lowered = []
+        with obs.using_obs() as sess:
+            for seed in (1, 2):
+                args = inputs(seed)
+                want = np.asarray(call(*args))
+                with ctx:
+                    got = np.asarray(call(*shard(args)))
+                np.testing.assert_allclose(got, want, rtol=1e-3,
+                                           atol=tol[{op!r}])
+                lowered.append(sess.metrics.get(
+                    "repro_compile_events_total").value(
+                        op={op!r}, stage="lower"))
+        assert lowered[0] > 0 and lowered[1] == lowered[0], lowered
+        cache = sess.metrics.get("repro_sharded_program_cache_total")
+        assert cache.value(op={op!r}, outcome="miss") == 1
+        assert cache.value(op={op!r}, outcome="hit") == 1
+        print("BUILT_ONCE_OK")
+    """)
+    assert "BUILT_ONCE_OK" in out
+
+
+def test_shard_ops_program_cache_is_never_stale():
+    """The cache keys on the resolved policy and the shape: a routed scan
+    under ``using_policy("fused")``, then under the default policy, then
+    at another shape, misses each time, traces under its own policy and
+    is correct."""
+    out = _run(4, """
+        from jax.sharding import NamedSharding
+        from repro import obs, ops
+        from repro.parallel.mesh_context import make_context
+
+        ctx = make_context("data=4")
+        shd = NamedSharding(ctx.mesh, P(None, "data"))
+        x = jax.random.normal(jax.random.PRNGKey(0), (3, 1024))
+        x2 = jax.random.normal(jax.random.PRNGKey(1), (5, 2048))
+        with obs.using_obs() as sess, ctx:
+            with ops.using_policy("fused"):
+                got = [ops.scan(jax.device_put(x, shd))]
+            got.append(ops.scan(jax.device_put(x, shd)))
+            got.append(ops.scan(jax.device_put(x2, shd)))
+        for g, v in zip(got, (x, x, x2)):
+            np.testing.assert_allclose(np.asarray(g),
+                                       np.cumsum(np.asarray(v), -1),
+                                       rtol=1e-3, atol=1e-2)
+        cache = sess.metrics.get("repro_sharded_program_cache_total")
+        assert cache.value(op="scan", outcome="miss") == 3
+        assert cache.value(op="scan", outcome="hit") == 0
+        routes = sess.events.events("sharded_dispatch")
+        resolved = {e["call"]: e["chosen_path"]
+                    for e in sess.events.events("resolution")
+                    if e["op"] == "scan"}
+        assert [r["call"] in resolved for r in routes] == [True] * 3
+        assert resolved[routes[0]["call"]] == "fused"
+        print("NEVER_STALE_OK")
+    """)
+    assert "NEVER_STALE_OK" in out
+
+
 def test_elastic_restart_across_mesh_sizes(tmp_path):
     """Fault-tolerance contract: checkpoint under a 4-device mesh, restore
     and continue under a 2-device mesh — values identical (elastic)."""

@@ -5,7 +5,9 @@ Interpret mode on the CPU cannot see what Mosaic refuses (block shapes that
 break the (8, 128) tiling rule, layouts that do not match XLA's). These
 tests hand the kernel entries shapes only, compile them for one chip of a
 ``v5e:2x2`` topology, and assert that the program holds the kernel
-(``tpu_custom_call``) — so a shape that falls back to XLA fails too.
+(``tpu_custom_call``) — so a shape that falls back to XLA fails too. The
+routed ``shard_ops`` programs are compiled for all four chips of it, at the
+four-chip cell's size.
 
 The topology is described only inside the module fixture below: only one
 process at a time may load the TPU library, and a worker that is never
@@ -13,15 +15,20 @@ given this file must not try.
 """
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
 
+from repro.core.policy import KernelPolicy
 from repro.kernels import backend
 from repro.kernels import ops  # noqa: F401  (registers the kernel entries)
+from repro.parallel import shard_ops
+from repro.parallel.mesh_context import MeshContext
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topology():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -35,8 +42,18 @@ def one_chip():
     enabled = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topology):
+    return SingleDeviceSharding(topology.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topology):
+    return Mesh(np.array(topology.devices).reshape(4), ("data",))
 
 
 def _compile_for_chip(one_chip, name, family, shapes):
@@ -88,3 +105,27 @@ def test_attention_compiles_for_v5e(one_chip, d):
     # kernel layout (B, H, L, D), 128-wide heads
     qkv = ((1, d // 128, 2048, 128), BF16)
     _compile_for_chip(one_chip, "attention", "tile", [qkv, qkv, qkv])
+
+
+@pytest.mark.parametrize("op, collective",
+                         [("reduce", "all-reduce"), ("scan", "all-gather")])
+def test_routed_program_compiles_for_v5e_2x2(four_chips, monkeypatch, op,
+                                             collective):
+    """The routed program of the four-chip cell: per-shard tile kernels
+    and the collective that combines them, within one chip's 16 GiB. The
+    host's probe for a native tile backend is pointed at the described
+    chip, as policy resolution would find it there."""
+    monkeypatch.setattr(backend, "native_tile_backend", lambda: "tile_tpu")
+    x = jax.ShapeDtypeStruct((1024, 65536), jnp.float32,
+                             sharding=NamedSharding(four_chips,
+                                                    P(None, "data")))
+    pol = KernelPolicy(path="tile", backend="tpu", interpret_fallback="error")
+    with MeshContext(mesh=four_chips):
+        plan = shard_ops.plan(op, x, policy=pol)
+        exe = plan.fn.lower(*plan.args).compile()
+    text = exe.as_text()
+    assert "tpu_custom_call" in text and collective in text
+    mem = exe.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert used < 16 * 2 ** 30, used
